@@ -74,9 +74,10 @@ _SCALING_CODE = textwrap.dedent("""
     from repro.core.config import ParallelConfig
     from repro.models.model import build_model
     from repro.serving.engine import Engine, Request
+    from repro.launch.mesh import make_mesh
 
     mesh_shape = __MESH_SHAPE__
-    mesh = (jax.make_mesh(mesh_shape, ("data", "model"))
+    mesh = (make_mesh(mesh_shape, ("data", "model"))
             if mesh_shape is not None else None)
     cfg = get_smoke_config("qwen2-7b")
     model = build_model(cfg, ParallelConfig(), mesh)
@@ -113,7 +114,19 @@ def _scaling_run(n_dev: int, mesh_shape=None):
     the XLA device-count flag must be set before jax initializes).
 
     ``mesh_shape`` is the (data, model) mesh; the model axis must divide
-    the smoke config's 4 attention heads, so 8 devices run as (2, 4)."""
+    the smoke config's 4 attention heads, so 8 devices run as (2, 4).
+
+    Refuses to run unless this process is on the CPU backend: the
+    children run on virtual CPU devices, and on an accelerator host their
+    numbers would be CPU numbers reported as serving scaling — while this
+    parent holds the chip, which a child could not use anyway."""
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            "serving scaling workload: virtual-CPU-device children only; "
+            f"this process runs on {jax.default_backend()!r}, so their "
+            "timings would not describe this device (and this process "
+            "holds it)"
+        )
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_dev}"
     env["JAX_PLATFORMS"] = "cpu"

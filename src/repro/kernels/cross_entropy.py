@@ -11,8 +11,14 @@ log-sum-exp so full logits never reach HBM:
     gather target logit if it falls in this vocab block
   final step emits per-token  loss = lse - logit[target].
 
-VMEM per step: bt·D + D·bv + bt·bv fp32 ≈ (128·4096 + 4096·512 + 128·512)·4
-≈ 10.5 MB at D=4096 — tiles shrink automatically for larger D.
+The MXU takes the hidden and weight tiles in their own dtype (bf16 in
+training) with fp32 accumulation; softmax statistics stay fp32.  The
+vocab block halves for wide models (``_fit_block_v``) so the (D × bv)
+weight tile, double-buffered, and the backward's fp32 (D × bv)
+accumulator fit the default 16 MiB of scoped VMEM: bv = 512 at
+D = 1280, 256 at D = 3584.  Per-token vectors (targets, loss, LSE and
+their cotangents) cross the kernel boundary as (T, 1) columns: a 1-D
+(block_t,) block does not match the layout XLA gives a 1-D array.
 
 Backward: the O(T) residual is the per-token LSE; block logits are
 recomputed on the MXU and the softmax gradient
@@ -44,6 +50,28 @@ from repro.kernels.tiling import pad_dim, pick_block
 NEG_INF = -1e30
 
 
+def _fit_block_v(d: int, block_v: int) -> int:
+    """Halve the vocab block (down to one lane tile) until the (D, bv)
+    weight tile and the backward's (D, bv) fp32 accumulator fit the
+    compiler's default 16 MiB of scoped VMEM with double buffering."""
+    while block_v > 128 and d * block_v * 12 > 12 << 20:
+        block_v //= 2
+    return block_v
+
+
+def _mxu(a, b, contract):
+    """fp32-accumulated matmul of two tiles in their own dtype.  bf16 x bf16
+    products are exact in the fp32 accumulator, so a caller's ``highest``
+    matmul precision has nothing to add there — and the TPU compiler has no
+    fp32-precision matmul of bf16 operands: pin the default for them."""
+    return jax.lax.dot_general(
+        a, b, (contract, ((), ())), preferred_element_type=jnp.float32,
+        precision=(
+            jax.lax.Precision.DEFAULT if a.dtype == jnp.bfloat16 else None
+        ),
+    )
+
+
 def _ce_kernel(
     h_ref, w_ref, tgt_ref,
     loss_ref, lse_ref,
@@ -62,11 +90,9 @@ def _ce_kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         t_scr[...] = jnp.full_like(t_scr, NEG_INF)
 
-    h = h_ref[...].astype(jnp.float32)              # (bt, D)
-    w = w_ref[...].astype(jnp.float32)              # (D, bv)
-    logits = jax.lax.dot_general(
-        h, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )                                                # (bt, bv)
+    h = h_ref[...]                                  # (bt, D)
+    w = w_ref[...]                                  # (D, bv)
+    logits = _mxu(h, w, ((1,), (0,)))              # (bt, bv)
     # mask vocab padding (last block may cover padded ids)
     col = vi * block_v + jax.lax.broadcasted_iota(jnp.int32, (block_t, block_v), 1)
     logits = jnp.where(col < vocab, logits, NEG_INF)
@@ -77,16 +103,15 @@ def _ce_kernel(
     l_scr[...] = jnp.exp(m_prev - m_new) * l_scr[...] + jnp.sum(p, axis=-1, keepdims=True)
     m_scr[...] = m_new
 
-    tgt = tgt_ref[...]                               # (bt,)
-    hit = col == tgt[:, None]
+    hit = col == tgt_ref[...]                        # tgt: (bt, 1)
     t_here = jnp.max(jnp.where(hit, logits, NEG_INF), axis=-1, keepdims=True)
     t_scr[...] = jnp.maximum(t_scr[...], t_here)
 
     @pl.when(vi == v_steps - 1)
     def _final():
         lse = m_scr[...] + jnp.log(jnp.maximum(l_scr[...], 1e-30))
-        loss_ref[...] = (lse - t_scr[...])[:, 0]
-        lse_ref[...] = lse[:, 0]
+        loss_ref[...] = lse - t_scr[...]
+        lse_ref[...] = lse
 
 
 def fused_cross_entropy(
@@ -105,11 +130,11 @@ def fused_cross_entropy(
     # non-multiple dims: zero-pad token rows (outputs sliced below) and
     # vocab columns (masked in-kernel via col < vocab)
     block_t, Tp = pick_block(T, block_t)
-    block_v, Vpp = pick_block(Vp, block_v)
+    block_v, Vpp = pick_block(Vp, _fit_block_v(D, block_v))
     v_steps = Vpp // block_v
     hidden_p = pad_dim(hidden, 0, Tp)
     w_p = pad_dim(w_out, 1, Vpp)
-    tgt_p = pad_dim(targets, 0, Tp)
+    tgt_p = pad_dim(targets, 0, Tp)[:, None]
     kernel = functools.partial(
         _ce_kernel,
         block_t=block_t,
@@ -123,15 +148,15 @@ def fused_cross_entropy(
         in_specs=[
             pl.BlockSpec((block_t, D), lambda ti, vi: (ti, 0)),
             pl.BlockSpec((D, block_v), lambda ti, vi: (0, vi)),
-            pl.BlockSpec((block_t,), lambda ti, vi: (ti,)),
+            pl.BlockSpec((block_t, 1), lambda ti, vi: (ti, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((block_t,), lambda ti, vi: (ti,)),
-            pl.BlockSpec((block_t,), lambda ti, vi: (ti,)),
+            pl.BlockSpec((block_t, 1), lambda ti, vi: (ti, 0)),
+            pl.BlockSpec((block_t, 1), lambda ti, vi: (ti, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((Tp,), jnp.float32),
-            jax.ShapeDtypeStruct((Tp,), jnp.float32),
+            jax.ShapeDtypeStruct((Tp, 1), jnp.float32),
+            jax.ShapeDtypeStruct((Tp, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_t, 1), jnp.float32),
@@ -140,7 +165,7 @@ def fused_cross_entropy(
         ],
         interpret=interpret,
     )(hidden_p, w_p, tgt_p)
-    return loss[:T], lse[:T]
+    return loss[:T, 0], lse[:T, 0]
 
 
 # --------------------------------------------------------------------- #
@@ -149,9 +174,7 @@ def fused_cross_entropy(
 def _block_dlogits(h, w, tgt, lse, gl, glse, vi, *, block_t, block_v, vocab):
     """Recompute one (bt, bv) logits block from the saved LSE and form the
     fused softmax gradient  (g_loss + g_lse)·p − g_loss·onehot  (fp32)."""
-    logits = jax.lax.dot_general(
-        h, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
+    logits = _mxu(h, w, ((1,), (0,)))
     col = vi * block_v + jax.lax.broadcasted_iota(jnp.int32, (block_t, block_v), 1)
     valid = col < vocab
     # exponent clamped at 0 (p <= 1 mathematically) so padded token rows —
@@ -160,7 +183,7 @@ def _block_dlogits(h, w, tgt, lse, gl, glse, vi, *, block_t, block_v, vocab):
     p = jnp.where(
         valid, jnp.exp(jnp.minimum(jnp.where(valid, logits, 0.0) - lse, 0.0)), 0.0
     )
-    onehot = jnp.where(valid & (col == tgt[:, None]), 1.0, 0.0)
+    onehot = jnp.where(valid & (col == tgt), 1.0, 0.0)
     return (gl + glse) * p - gl * onehot
 
 
@@ -180,16 +203,13 @@ def _ce_dh_kernel(
     def _init():
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    h = h_ref[...].astype(jnp.float32)
-    w = w_ref[...].astype(jnp.float32)
+    h = h_ref[...]
+    w = w_ref[...]
     dlogits = _block_dlogits(
-        h, w, tgt_ref[...], lse_ref[...][:, None],
-        gl_ref[...][:, None], glse_ref[...][:, None], vi,
+        h, w, tgt_ref[...], lse_ref[...], gl_ref[...], glse_ref[...], vi,
         block_t=block_t, block_v=block_v, vocab=vocab,
     )
-    acc_scr[...] += jax.lax.dot_general(
-        dlogits, w, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )                                                # (bt, D)
+    acc_scr[...] += _mxu(dlogits.astype(w.dtype), w, ((1,), (1,)))  # (bt, D)
 
     @pl.when(vi == v_steps - 1)
     def _final():
@@ -213,16 +233,13 @@ def _ce_dw_kernel(
     def _init():
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    h = h_ref[...].astype(jnp.float32)
-    w = w_ref[...].astype(jnp.float32)
+    h = h_ref[...]
+    w = w_ref[...]
     dlogits = _block_dlogits(
-        h, w, tgt_ref[...], lse_ref[...][:, None],
-        gl_ref[...][:, None], glse_ref[...][:, None], vi,
+        h, w, tgt_ref[...], lse_ref[...], gl_ref[...], glse_ref[...], vi,
         block_t=block_t, block_v=block_v, vocab=vocab,
     )
-    acc_scr[...] += jax.lax.dot_general(
-        h, dlogits, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )                                                # (D, bv)
+    acc_scr[...] += _mxu(h, dlogits.astype(h.dtype), ((0,), (0,)))  # (D, bv)
 
     @pl.when(ti == t_steps - 1)
     def _final():
@@ -247,17 +264,17 @@ def fused_cross_entropy_bwd(
     Vp = w_out.shape[1]
     vocab = vocab or Vp
     block_t, Tp = pick_block(T, block_t)
-    block_v, Vpp = pick_block(Vp, block_v)
+    block_v, Vpp = pick_block(Vp, _fit_block_v(D, block_v))
     t_steps = Tp // block_t
     v_steps = Vpp // block_v
     # padded token rows carry zero loss/lse cotangents -> zero dlogits;
     # padded vocab columns are masked via col < vocab
     hidden = pad_dim(hidden, 0, Tp)
     w_pad = pad_dim(w_out, 1, Vpp)
-    targets = pad_dim(targets, 0, Tp)
-    lse = pad_dim(lse, 0, Tp)
-    gl = pad_dim(g_loss.astype(jnp.float32), 0, Tp)
-    glse = pad_dim(g_lse.astype(jnp.float32), 0, Tp)
+    targets = pad_dim(targets, 0, Tp)[:, None]
+    lse = pad_dim(lse, 0, Tp)[:, None]
+    gl = pad_dim(g_loss.astype(jnp.float32), 0, Tp)[:, None]
+    glse = pad_dim(g_lse.astype(jnp.float32), 0, Tp)[:, None]
 
     dh_kernel = functools.partial(
         _ce_dh_kernel,
@@ -269,10 +286,10 @@ def fused_cross_entropy_bwd(
         in_specs=[
             pl.BlockSpec((block_t, D), lambda ti, vi: (ti, 0)),
             pl.BlockSpec((D, block_v), lambda ti, vi: (0, vi)),
-            pl.BlockSpec((block_t,), lambda ti, vi: (ti,)),
-            pl.BlockSpec((block_t,), lambda ti, vi: (ti,)),
-            pl.BlockSpec((block_t,), lambda ti, vi: (ti,)),
-            pl.BlockSpec((block_t,), lambda ti, vi: (ti,)),
+            pl.BlockSpec((block_t, 1), lambda ti, vi: (ti, 0)),
+            pl.BlockSpec((block_t, 1), lambda ti, vi: (ti, 0)),
+            pl.BlockSpec((block_t, 1), lambda ti, vi: (ti, 0)),
+            pl.BlockSpec((block_t, 1), lambda ti, vi: (ti, 0)),
         ],
         out_specs=pl.BlockSpec((block_t, D), lambda ti, vi: (ti, 0)),
         out_shape=jax.ShapeDtypeStruct((Tp, D), hidden.dtype),
@@ -290,10 +307,10 @@ def fused_cross_entropy_bwd(
         in_specs=[
             pl.BlockSpec((block_t, D), lambda vi, ti: (ti, 0)),
             pl.BlockSpec((D, block_v), lambda vi, ti: (0, vi)),
-            pl.BlockSpec((block_t,), lambda vi, ti: (ti,)),
-            pl.BlockSpec((block_t,), lambda vi, ti: (ti,)),
-            pl.BlockSpec((block_t,), lambda vi, ti: (ti,)),
-            pl.BlockSpec((block_t,), lambda vi, ti: (ti,)),
+            pl.BlockSpec((block_t, 1), lambda vi, ti: (ti, 0)),
+            pl.BlockSpec((block_t, 1), lambda vi, ti: (ti, 0)),
+            pl.BlockSpec((block_t, 1), lambda vi, ti: (ti, 0)),
+            pl.BlockSpec((block_t, 1), lambda vi, ti: (ti, 0)),
         ],
         out_specs=pl.BlockSpec((D, block_v), lambda vi, ti: (0, vi)),
         out_shape=jax.ShapeDtypeStruct((D, Vpp), w_out.dtype),

@@ -15,8 +15,14 @@ TPU-native adaptation of TransformerEngine-class fused attention:
   * supports causal masking, sliding window, logit softcap, and a q-position
     offset for decode.
 
-Backward pass (FlashAttention-2 style, three kernels):
-  * ``_fa_delta_kernel``  — Δ_i = Σ_d dO_id·O_id per q row (precompute).
+Per-row statistics (the forward's LSE, the backward's LSE and Δ) cross
+the kernel boundary as (B·H, S, 128) arrays replicated over one lane tile,
+so every block's last two dims are (block_q, 128): a (block_q,) row vector
+is not a block shape the TPU compiler accepts.  The saved residual is the
+compact (B·H, S) LSE.
+
+Backward pass (FlashAttention-2 style):
+  * Δ_i = Σ_d dO_id·O_id per q row — one fused XLA reduction.
   * ``_fa_dq_kernel``     — grid (B·H, q_blocks, kv_blocks); recomputes
     block probabilities from the saved per-row LSE and accumulates dQ in
     VMEM scratch across kv steps.
@@ -44,6 +50,7 @@ import jax.experimental.pallas.tpu as pltpu
 from repro.kernels.tiling import pad_dim, pick_block
 
 NEG_INF = -1e30
+LANES = 128  # per-row statistics travel replicated over one lane tile
 
 
 def _block_mask(qi, ki, *, block_q, block_k, causal, window, q_offset, kv_len):
@@ -145,8 +152,10 @@ def _fa_kernel(
         denom = jnp.maximum(l, 1e-30)
         o_ref[0] = (acc_scr[...] / denom).astype(o_ref.dtype)
         # per-row logsumexp residual for the backward pass (fully-masked
-        # rows get lse ≈ NEG_INF, which the bwd kernels treat as inert)
-        lse_ref[0] = (m_scr[...] + jnp.log(denom))[:, 0]
+        # rows get lse ≈ NEG_INF, which the bwd kernels treat as inert),
+        # replicated over a full lane tile: a (block_q,) row vector is not
+        # a block shape the TPU compiler accepts
+        lse_ref[0] = jnp.broadcast_to(m_scr[...] + jnp.log(denom), lse_ref.shape[1:])
 
 
 def _head_major(x):
@@ -194,7 +203,7 @@ def flash_attention_fwd(
         return (batch * Hkv + head // group, ki, 0)
 
     def lse_map(b, qi, ki):
-        return (b, qi)
+        return (b, qi, 0)
 
     kernel = functools.partial(
         _fa_kernel,
@@ -218,11 +227,11 @@ def flash_attention_fwd(
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, D), q_map),
-            pl.BlockSpec((1, block_q), lse_map),
+            pl.BlockSpec((1, block_q, LANES), lse_map),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * H, Sp, D), q.dtype),
-            jax.ShapeDtypeStruct((B * H, Sp), jnp.float32),
+            jax.ShapeDtypeStruct((B * H, Sp, LANES), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
@@ -231,7 +240,7 @@ def flash_attention_fwd(
         ],
         interpret=interpret,
     )(qh, kh, vh)
-    out, lse = out[:, :S], lse[:, :S]
+    out, lse = out[:, :S], lse[:, :S, 0]
     return jnp.transpose(out.reshape(B, H, S, D), (0, 2, 1, 3)), lse
 
 
@@ -259,12 +268,6 @@ def flash_attention(
 # --------------------------------------------------------------------- #
 # backward
 # --------------------------------------------------------------------- #
-def _fa_delta_kernel(o_ref, do_ref, delta_ref):
-    o = o_ref[0].astype(jnp.float32)
-    do = do_ref[0].astype(jnp.float32)
-    delta_ref[0] = jnp.sum(o * do, axis=-1)
-
-
 def _recompute_p_ds(
     q, k, v, do, lse, delta, qi, ki, *,
     scale, causal, window, softcap, block_q, block_k, q_offset, kv_len,
@@ -328,8 +331,8 @@ def _fa_dq_kernel(
         k = k_ref[0].astype(jnp.float32)
         v = v_ref[0].astype(jnp.float32)
         do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0][:, None]                # (bq, 1)
-        delta = delta_ref[0][:, None]
+        lse = lse_ref[0][:, :1]                  # (bq, 1)
+        delta = delta_ref[0][:, :1]
         _, ds = _recompute_p_ds(
             q, k, v, do, lse, delta, qi, ki,
             scale=scale, causal=causal, window=window, softcap=softcap,
@@ -383,8 +386,8 @@ def _fa_dkv_kernel(
         k = k_ref[0].astype(jnp.float32)
         v = v_ref[0].astype(jnp.float32)
         do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0][:, None]
-        delta = delta_ref[0][:, None]
+        lse = lse_ref[0][:, :1]
+        delta = delta_ref[0][:, :1]
         p, ds = _recompute_p_ds(
             q, k, v, do, lse, delta, qi, ki,
             scale=scale, causal=causal, window=window, softcap=softcap,
@@ -445,20 +448,16 @@ def flash_attention_bwd(
     vh = pad_dim(_head_major(v), 1, Tp)
     oh = pad_dim(_head_major(out), 1, Sp)
     doh = pad_dim(_head_major(do), 1, Sp)
-    lse = pad_dim(lse, 1, Sp)
 
-    # Δ = rowsum(dO ⊙ O) precompute
-    delta = pl.pallas_call(
-        _fa_delta_kernel,
-        grid=(B * H, q_steps),
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, qi: (b, qi, 0)),
-            pl.BlockSpec((1, block_q, D), lambda b, qi: (b, qi, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q), lambda b, qi: (b, qi)),
-        out_shape=jax.ShapeDtypeStruct((B * H, Sp), jnp.float32),
-        interpret=interpret,
-    )(oh, doh)
+    # per-row statistics, replicated over one lane tile like the forward's
+    # LSE output: lse and Δ = rowsum(dO ⊙ O) (one fused XLA reduction)
+    def rows(x):
+        return jnp.broadcast_to(pad_dim(x, 1, Sp)[..., None], (B * H, Sp, LANES))
+
+    lse = rows(lse)
+    delta = rows(jnp.sum(
+        oh.astype(jnp.float32) * doh.astype(jnp.float32), axis=-1
+    ))
 
     # ---- dQ: grid (B·H, q, kv), kv innermost accumulates into scratch ----
     def q_map(b, qi, ki):
@@ -470,7 +469,7 @@ def flash_attention_bwd(
         return (batch * Hkv + head // group, ki, 0)
 
     def row_map(b, qi, ki):
-        return (b, qi)
+        return (b, qi, 0)
 
     dq_kernel = functools.partial(
         _fa_dq_kernel,
@@ -486,8 +485,8 @@ def flash_attention_bwd(
             pl.BlockSpec((1, block_k, D), kv_map),
             pl.BlockSpec((1, block_k, D), kv_map),
             pl.BlockSpec((1, block_q, D), q_map),
-            pl.BlockSpec((1, block_q), row_map),
-            pl.BlockSpec((1, block_q), row_map),
+            pl.BlockSpec((1, block_q, LANES), row_map),
+            pl.BlockSpec((1, block_q, LANES), row_map),
         ],
         out_specs=pl.BlockSpec((1, block_q, D), q_map),
         out_shape=jax.ShapeDtypeStruct((B * H, Sp, D), q.dtype),
@@ -512,7 +511,7 @@ def flash_attention_bwd(
         kvh = b % Hkv
         g = j // q_steps
         qi = j % q_steps
-        return (batch * H + kvh * group + g, qi)
+        return (batch * H + kvh * group + g, qi, 0)
 
     def kv_map2(b, ki, j):
         return (b, ki, 0)
@@ -529,8 +528,8 @@ def flash_attention_bwd(
         in_specs=[
             pl.BlockSpec((1, block_q, D), q_map2),
             pl.BlockSpec((1, block_q, D), q_map2),
-            pl.BlockSpec((1, block_q), row_map2),
-            pl.BlockSpec((1, block_q), row_map2),
+            pl.BlockSpec((1, block_q, LANES), row_map2),
+            pl.BlockSpec((1, block_q, LANES), row_map2),
             pl.BlockSpec((1, block_k, D), kv_map2),
             pl.BlockSpec((1, block_k, D), kv_map2),
         ],

@@ -55,6 +55,12 @@ def _resolve(impl: str, interpret: bool) -> Tuple[str, bool]:
     return impl, interpret
 
 
+def is_pallas(impl: str) -> bool:
+    """Whether ``impl`` runs the Pallas kernels here: a mesh must then run
+    them per shard (``ShardingCtx.per_shard``)."""
+    return _resolve(impl, False)[0] == "pallas"
+
+
 # --------------------------------------------------------------------- #
 # attention
 # --------------------------------------------------------------------- #

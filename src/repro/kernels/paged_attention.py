@@ -8,13 +8,14 @@ Three kernels make that layout a first-class serving path:
 ``paged_flash_decode``
     The flash-decoding combine of ``flash_decode.py`` with the contiguous
     cache replaced by block-table indirection: grid
-    (batch, kv_heads, pages_per_seq), and the K/V *page* tile for grid
-    step ``(b, h, p)`` is gathered straight out of the pool by the
-    BlockSpec index map reading the prefetched block table
+    (batch, pages_per_seq), and the K/V *page* tile (every KV head of
+    it: the TPU compiler takes a block whose last two dims are the full
+    (Hkv, D)) for grid step ``(b, p)`` is gathered straight out of the
+    pool by the BlockSpec index map reading the prefetched block table
     (``PrefetchScalarGridSpec``) — the gather is the DMA, no
-    materialized (B, T) cache ever exists.  Combine state (m, l, acc)
-    lives in VMEM scratch across the sequential page axis, exactly like
-    the contiguous kernel.
+    materialized (B, T) cache ever exists.  Per-head combine state
+    (m, l, acc) lives in VMEM scratch across the sequential page axis,
+    exactly like the contiguous kernel.
 
 ``paged_flash_prefill``
     Chunked/suffix prefill attention through the same block table: the
@@ -22,9 +23,12 @@ Three kernels make that layout a first-class serving path:
     positions ``starts[b] + i`` (``starts`` supports prefix-cache skips
     and chunked prefill — the chunk attends to every already-written
     page, including pages shared from the prefix cache, plus itself,
-    under a causal mask shifted by the query offset).  Same grid and
-    VMEM running-LSE combine as the decode kernel, with (S·group) query
-    rows instead of ``group``.
+    under a causal mask shifted by the query offset).  Same VMEM
+    running-LSE combine as the decode kernel over grid
+    (batch, query_blocks, pages_per_seq), with (group·bs) query rows per
+    KV head instead of ``group``: queries travel head-major,
+    (B, Hkv, group, S, D), so a block of ``bs`` chunk tokens has last two
+    dims (bs, D).
 
 ``paged_kv_write``
     Per-token decode cache insert: grid (B,), each step rewrites ONE page
@@ -48,7 +52,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-NEG_INF = -1e30
+from repro.kernels import tiling
+from repro.kernels.flash_decode import attend_block, finish, init_scratch
+
+# paged prefill query block: chunk tokens per grid step (a multiple of 16)
+_BLOCK_S = 128
 
 
 # --------------------------------------------------------------------- #
@@ -57,10 +65,10 @@ NEG_INF = -1e30
 def _pa_kernel(
     bt_ref,      # (B, pages_per_seq) scalar-prefetch block table
     len_ref,     # (B,) scalar-prefetch valid lengths
-    q_ref,       # (1, 1, 1, group, D)
-    k_ref,       # (1, page, 1, D)  — the page picked by the index map
+    q_ref,       # (1, Hkv, group, D)
+    k_ref,       # (1, page, Hkv, D)  — the page picked by the index map
     v_ref,
-    o_ref,       # (1, 1, 1, group, D)
+    o_ref,       # (1, Hkv, group, D)
     m_scr, l_scr, acc_scr,
     *,
     scale: float,
@@ -69,44 +77,30 @@ def _pa_kernel(
     softcap: float,
 ):
     b = pl.program_id(0)
-    pi = pl.program_id(2)
+    pi = pl.program_id(1)
 
     @pl.when(pi == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    q = q_ref[0, 0, 0].astype(jnp.float32)         # (group, D)
-    k = k_ref[0, :, 0].astype(jnp.float32)         # (page, D)
-    v = v_ref[0, :, 0].astype(jnp.float32)
-
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale                                       # (group, page)
-    if softcap > 0.0:
-        s = softcap * jnp.tanh(s / softcap)
+        init_scratch(m_scr, l_scr, acc_scr)
 
     # logical position of each page row; pages past the valid length are
     # the null page — masked out entirely (m stays NEG_INF for len==0).
-    pos = pi * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    s = jnp.where(pos < len_ref[b], s, NEG_INF)
-
-    m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    p = jnp.where(m_new <= NEG_INF / 2, 0.0, p)
-    alpha = jnp.exp(m_prev - m_new)
-    m_scr[...] = m_new
-    l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=-1, keepdims=True)
-    acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
+    group = q_ref.shape[2]
+    pos = pi * page + jax.lax.broadcasted_iota(jnp.int32, (group, page), 1)
+    valid = pos < len_ref[b]
+    for h in range(q_ref.shape[1]):
+        attend_block(
+            h,
+            q_ref[0, h].astype(jnp.float32),        # (group, D)
+            k_ref[0, :, h].astype(jnp.float32),     # (page, D)
+            v_ref[0, :, h].astype(jnp.float32),
+            valid, m_scr, l_scr, acc_scr, scale=scale, softcap=softcap,
+        )
 
     @pl.when(pi == p_steps - 1)
     def _final():
-        denom = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, 0, 0] = (acc_scr[...] / denom).astype(o_ref.dtype)
+        for h in range(q_ref.shape[1]):
+            o_ref[0, h] = finish(h, l_scr, acc_scr).astype(o_ref.dtype)
 
 
 def paged_flash_decode(
@@ -126,7 +120,7 @@ def paged_flash_decode(
     group = H // Hkv
     scale = 1.0 / math.sqrt(D)
 
-    qg = q.reshape(B, 1, Hkv, group, D)
+    qg = q.reshape(B, Hkv, group, D)
 
     kernel = functools.partial(
         _pa_kernel,
@@ -136,28 +130,28 @@ def paged_flash_decode(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,      # block_table, lengths
-            grid=(B, Hkv, pages_per_seq),
+            grid=(B, pages_per_seq),
             in_specs=[
                 pl.BlockSpec(
-                    (1, 1, 1, group, D), lambda b, h, pi, bt, ln: (b, 0, h, 0, 0)
+                    (1, Hkv, group, D), lambda b, pi, bt, ln: (b, 0, 0, 0)
                 ),
                 pl.BlockSpec(
-                    (1, page, 1, D), lambda b, h, pi, bt, ln: (bt[b, pi], 0, h, 0)
+                    (1, page, Hkv, D), lambda b, pi, bt, ln: (bt[b, pi], 0, 0, 0)
                 ),
                 pl.BlockSpec(
-                    (1, page, 1, D), lambda b, h, pi, bt, ln: (bt[b, pi], 0, h, 0)
+                    (1, page, Hkv, D), lambda b, pi, bt, ln: (bt[b, pi], 0, 0, 0)
                 ),
             ],
             out_specs=pl.BlockSpec(
-                (1, 1, 1, group, D), lambda b, h, pi, bt, ln: (b, 0, h, 0, 0)
+                (1, Hkv, group, D), lambda b, pi, bt, ln: (b, 0, 0, 0)
             ),
             scratch_shapes=[
-                pltpu.VMEM((group, 1), jnp.float32),
-                pltpu.VMEM((group, 1), jnp.float32),
-                pltpu.VMEM((group, D), jnp.float32),
+                pltpu.VMEM((Hkv, group, 1), jnp.float32),
+                pltpu.VMEM((Hkv, group, 1), jnp.float32),
+                pltpu.VMEM((Hkv, group, D), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, 1, Hkv, group, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, group, D), q.dtype),
         interpret=interpret,
     )(block_table.astype(jnp.int32), lengths.astype(jnp.int32), qg, k_pool, v_pool)
     return out.reshape(B, 1, H, D)
@@ -170,64 +164,50 @@ def _pp_kernel(
     bt_ref,      # (B, pages_per_seq) scalar-prefetch block table
     start_ref,   # (B,) scalar-prefetch query offset (first query's position)
     len_ref,     # (B,) scalar-prefetch total valid context length
-    q_ref,       # (1, S, 1, group, D)
-    k_ref,       # (1, page, 1, D)  — the page picked by the index map
+    q_ref,       # (1, Hkv, group, bs, D)
+    k_ref,       # (1, page, Hkv, D)  — the page picked by the index map
     v_ref,
-    o_ref,       # (1, S, 1, group, D)
-    m_scr, l_scr, acc_scr,    # (S·group, 1/1/D)
+    o_ref,       # (1, Hkv, group, bs, D)
+    m_scr, l_scr, acc_scr,    # (Hkv, group·bs, 1/1/D)
     *,
     scale: float,
     page: int,
     p_steps: int,
-    group: int,
     softcap: float,
 ):
     b = pl.program_id(0)
+    qi = pl.program_id(1)
     pi = pl.program_id(2)
 
     @pl.when(pi == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        init_scratch(m_scr, l_scr, acc_scr)
 
-    S = q_ref.shape[1]
-    q = q_ref[0, :, 0].astype(jnp.float32).reshape(S * group, -1)  # (S·g, D)
-    k = k_ref[0, :, 0].astype(jnp.float32)                         # (page, D)
-    v = v_ref[0, :, 0].astype(jnp.float32)
-
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale                                                      # (S·g, page)
-    if softcap > 0.0:
-        s = softcap * jnp.tanh(s / softcap)
-
-    # causal mask shifted by the query offset: query row r (token index
-    # r // group within the chunk) sits at logical position start + r//group
-    # and may attend to k positions <= its own; pages past the valid
-    # length (incl. the null page in unallocated entries) are masked out.
-    q_pos = start_ref[b] + (
-        jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // group
+    _, Hkv, group, bs, D = q_ref.shape
+    rows = group * bs
+    # causal mask shifted by the query offset: query row r (chunk token
+    # qi·bs + r % bs) sits at logical position start + qi·bs + r % bs and
+    # may attend to k positions <= its own; pages past the valid length
+    # (incl. the null page in unallocated entries) are masked out.
+    q_pos = start_ref[b] + qi * bs + (
+        jax.lax.broadcasted_iota(jnp.int32, (rows, page), 0) % bs
     )
-    k_pos = pi * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    s = jnp.where((k_pos <= q_pos) & (k_pos < len_ref[b]), s, NEG_INF)
-
-    m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    p = jnp.where(m_new <= NEG_INF / 2, 0.0, p)
-    alpha = jnp.exp(m_prev - m_new)
-    m_scr[...] = m_new
-    l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=-1, keepdims=True)
-    acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
+    k_pos = pi * page + jax.lax.broadcasted_iota(jnp.int32, (rows, page), 1)
+    valid = (k_pos <= q_pos) & (k_pos < len_ref[b])
+    for h in range(Hkv):
+        attend_block(
+            h,
+            q_ref[0, h].astype(jnp.float32).reshape(rows, D),
+            k_ref[0, :, h].astype(jnp.float32),     # (page, D)
+            v_ref[0, :, h].astype(jnp.float32),
+            valid, m_scr, l_scr, acc_scr, scale=scale, softcap=softcap,
+        )
 
     @pl.when(pi == p_steps - 1)
     def _final():
-        denom = jnp.maximum(l_scr[...], 1e-30)
-        out = (acc_scr[...] / denom).reshape(S, group, -1)
-        o_ref[0, :, 0] = out.astype(o_ref.dtype)
+        for h in range(Hkv):
+            out = finish(h, l_scr, acc_scr).reshape(group, bs, D)
+            o_ref[0, h] = out.astype(o_ref.dtype)
 
 
 def paged_flash_prefill(
@@ -247,49 +227,47 @@ def paged_flash_prefill(
     assert H % Hkv == 0
     group = H // Hkv
     scale = 1.0 / math.sqrt(D)
+    # query blocks of bs <= _BLOCK_S chunk tokens (a multiple of 16, the
+    # bf16 sublane tile, so the in-kernel (group, bs) -> rows merge is
+    # layout-free); padded tail rows are computed and sliced off
+    bs = min(_BLOCK_S, S + (-S % 16))
+    Sp = S + (-S % bs)
 
-    qg = q.reshape(B, S, Hkv, group, D)
+    # (B, S, Hkv·group, D) -> (B, Hkv, group, Sp, D): the block's last two
+    # dims are (bs, D) and every KV head's queries travel together
+    qg = jnp.transpose(q.reshape(B, S, Hkv, group, D), (0, 2, 3, 1, 4))
+    qg = tiling.pad_dim(qg, 3, Sp)
 
     kernel = functools.partial(
         _pp_kernel,
-        scale=scale, page=page, p_steps=pages_per_seq, group=group,
-        softcap=softcap,
+        scale=scale, page=page, p_steps=pages_per_seq, softcap=softcap,
     )
+    q_map = lambda b, qi, pi, bt, st, ln: (b, 0, 0, qi, 0)  # noqa: E731
+    kv_map = lambda b, qi, pi, bt, st, ln: (bt[b, pi], 0, 0, 0)  # noqa: E731
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,      # block_table, starts, lengths
-            grid=(B, Hkv, pages_per_seq),
+            grid=(B, Sp // bs, pages_per_seq),
             in_specs=[
-                pl.BlockSpec(
-                    (1, S, 1, group, D),
-                    lambda b, h, pi, bt, st, ln: (b, 0, h, 0, 0),
-                ),
-                pl.BlockSpec(
-                    (1, page, 1, D),
-                    lambda b, h, pi, bt, st, ln: (bt[b, pi], 0, h, 0),
-                ),
-                pl.BlockSpec(
-                    (1, page, 1, D),
-                    lambda b, h, pi, bt, st, ln: (bt[b, pi], 0, h, 0),
-                ),
+                pl.BlockSpec((1, Hkv, group, bs, D), q_map),
+                pl.BlockSpec((1, page, Hkv, D), kv_map),
+                pl.BlockSpec((1, page, Hkv, D), kv_map),
             ],
-            out_specs=pl.BlockSpec(
-                (1, S, 1, group, D),
-                lambda b, h, pi, bt, st, ln: (b, 0, h, 0, 0),
-            ),
+            out_specs=pl.BlockSpec((1, Hkv, group, bs, D), q_map),
             scratch_shapes=[
-                pltpu.VMEM((S * group, 1), jnp.float32),
-                pltpu.VMEM((S * group, 1), jnp.float32),
-                pltpu.VMEM((S * group, D), jnp.float32),
+                pltpu.VMEM((Hkv, group * bs, 1), jnp.float32),
+                pltpu.VMEM((Hkv, group * bs, 1), jnp.float32),
+                pltpu.VMEM((Hkv, group * bs, D), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, S, Hkv, group, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, group, Sp, D), q.dtype),
         interpret=interpret,
     )(
         block_table.astype(jnp.int32), starts.astype(jnp.int32),
         lengths.astype(jnp.int32), qg, k_pool, v_pool,
     )
+    out = jnp.transpose(out[:, :, :, :S], (0, 3, 1, 2, 4))
     return out.reshape(B, S, H, D)
 
 

@@ -41,13 +41,15 @@ two implementations in lockstep by construction.
 """
 from __future__ import annotations
 
-import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+import jax.experimental.pallas.tpu as pltpu
 
 NEG_INF = -1e30
+_LANES = 128
 _BISECT_ITERS = 32
 
 
@@ -76,7 +78,10 @@ def gumbel_noise(seed: jax.Array, step: jax.Array, idx: jax.Array) -> jax.Array:
     h = _fmix32(h ^ (step * jnp.uint32(0x85EBCA77)))
     u = _fmix32(h ^ (idx * jnp.uint32(0x9E3779B1)))
     # top 24 bits -> uniform strictly inside (0, 1); +0.5 keeps log finite
-    uf = ((u >> jnp.uint32(8)).astype(jnp.float32) + 0.5) * (1.0 / (1 << 24))
+    # (through int32: the 24-bit value is the same, and the TPU compiler
+    # converts signed integers to float, not unsigned ones)
+    top = jax.lax.bitcast_convert_type(u >> jnp.uint32(8), jnp.int32)
+    uf = (top.astype(jnp.float32) + 0.5) * (1.0 / (1 << 24))
     return -jnp.log(-jnp.log(uf))
 
 
@@ -87,23 +92,26 @@ def _sample_rows(x, temp, top_k, top_p, seed, step, idx, *,
                  iters: int = _BISECT_ITERS):
     """Sample one token per row of ``x``.
 
-    ``x``: (R, V) f32 raw logits (padded / masked-vocab entries at
-    ``NEG_INF``); ``temp``/``top_p`` (R, 1) f32, ``top_k`` (R, 1) i32
-    (``0`` disables), ``seed``/``step`` (R, 1) uint32, ``idx`` (R, V)
-    i32 vocab ids.  Returns ``(tok (R,1) i32, logp (R,1) f32)`` where
+    ``x``: (R, *V) f32 raw logits (padded / masked-vocab entries at
+    ``NEG_INF``), the vocab spread over one or more trailing axes;
+    ``temp``/``top_p`` f32, ``top_k`` i32 (``0`` disables),
+    ``seed``/``step`` uint32, each (R, 1, ...) with x's rank; ``idx`` i32
+    vocab ids shaped like x.  Returns ``(tok i32, logp f32)``, each
+    (R, 1, ...), where
     ``logp`` is the log-probability of the chosen token under the
     filtered, temperature-scaled, renormalized distribution (for greedy
     rows: under the full T=1 softmax).
     """
-    V = x.shape[-1]
+    V = math.prod(x.shape[1:])
+    ax = tuple(range(1, x.ndim))          # the vocab axes
     valid = x > NEG_INF / 2
     greedy = temp <= 0.0
     t = jnp.where(greedy, 1.0, temp)
     z = jnp.where(valid, x / t, NEG_INF)
-    m = jnp.max(z, axis=-1, keepdims=True)
-    mn = jnp.min(jnp.where(valid, z, m), axis=-1, keepdims=True)
+    m = jnp.max(z, axis=ax, keepdims=True)
+    mn = jnp.min(jnp.where(valid, z, m), axis=ax, keepdims=True)
     e = jnp.where(valid, jnp.exp(z - m), 0.0)
-    Z = jnp.sum(e, axis=-1, keepdims=True)
+    Z = jnp.sum(e, axis=ax, keepdims=True)
 
     k = jnp.where(top_k <= 0, jnp.int32(V), jnp.clip(top_k, 1, V))
     k = k.astype(jnp.float32)
@@ -114,12 +122,12 @@ def _sample_rows(x, temp, top_k, top_p, seed, step, idx, *,
     def body(_, c):
         lo_k, hi_k, lo_p, hi_p = c
         mid = 0.5 * (lo_k + hi_k)
-        cnt = jnp.sum(jnp.where(z >= mid, 1.0, 0.0), axis=-1, keepdims=True)
+        cnt = jnp.sum(jnp.where(z >= mid, 1.0, 0.0), axis=ax, keepdims=True)
         ok = cnt >= k
         lo_k = jnp.where(ok, mid, lo_k)
         hi_k = jnp.where(ok, hi_k, mid)
         mid = 0.5 * (lo_p + hi_p)
-        mass = jnp.sum(jnp.where(z >= mid, e, 0.0), axis=-1, keepdims=True)
+        mass = jnp.sum(jnp.where(z >= mid, e, 0.0), axis=ax, keepdims=True)
         ok = mass >= pZ
         lo_p = jnp.where(ok, mid, lo_p)
         hi_p = jnp.where(ok, hi_p, mid)
@@ -142,14 +150,14 @@ def _sample_rows(x, temp, top_k, top_p, seed, step, idx, *,
     g = jnp.where(greedy, 0.0, g)
     keep = valid & (z >= tau)
     y = jnp.where(keep, z + g, NEG_INF)
-    ymax = jnp.max(y, axis=-1, keepdims=True)
+    ymax = jnp.max(y, axis=ax, keepdims=True)
     # first index attaining the max — jnp.argmax's tie-break, so the
     # greedy path is bit-identical to argmax decoding
     tok = jnp.min(
-        jnp.where(y == ymax, idx, jnp.int32(V)), axis=-1, keepdims=True
+        jnp.where(y == ymax, idx, jnp.int32(V)), axis=ax, keepdims=True
     )
-    z_tok = jnp.max(jnp.where(idx == tok, z, NEG_INF), axis=-1, keepdims=True)
-    Zf = jnp.sum(jnp.where(keep, e, 0.0), axis=-1, keepdims=True)
+    z_tok = jnp.max(jnp.where(idx == tok, z, NEG_INF), axis=ax, keepdims=True)
+    Zf = jnp.sum(jnp.where(keep, e, 0.0), axis=ax, keepdims=True)
     logp = z_tok - m - jnp.log(jnp.maximum(Zf, 1e-30))
     return tok.astype(jnp.int32), logp
 
@@ -173,21 +181,29 @@ def sample_xla(logits, temperature, top_k, top_p, seed, step):
 # --------------------------------------------------------------------- #
 # Pallas kernel
 # --------------------------------------------------------------------- #
-def _sample_kernel(x_ref, temp_ref, topk_ref, topp_ref, seed_ref, step_ref,
-                   tok_ref, logp_ref):
-    x = x_ref[...]                                        # (1, Vp) f32
-    idx = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+def _sample_kernel(temp_ref, topk_ref, topp_ref, seed_ref, step_ref,
+                   x_ref, tok_ref, logp_ref):
+    b = pl.program_id(0)
+    x = x_ref[...]                                        # (1, Vp/128, 128)
+    idx = (
+        jax.lax.broadcasted_iota(jnp.int32, x.shape, 1) * _LANES
+        + jax.lax.broadcasted_iota(jnp.int32, x.shape, 2)
+    )
+
+    def param(ref, dtype):
+        return jnp.full((1, 1, 1), ref[b], dtype)
+
     tok, logp = _sample_rows(
         x,
-        temp_ref[...].reshape(1, 1),
-        topk_ref[...].reshape(1, 1),
-        topp_ref[...].reshape(1, 1),
-        seed_ref[...].reshape(1, 1),
-        step_ref[...].reshape(1, 1),
+        param(temp_ref, jnp.float32),
+        param(topk_ref, jnp.int32),
+        param(topp_ref, jnp.float32),
+        jax.lax.bitcast_convert_type(param(seed_ref, jnp.int32), jnp.uint32),
+        jax.lax.bitcast_convert_type(param(step_ref, jnp.int32), jnp.uint32),
         idx,
     )
-    tok_ref[...] = tok
-    logp_ref[...] = logp
+    tok_ref[...] = jnp.broadcast_to(tok, tok_ref.shape)
+    logp_ref[...] = jnp.broadcast_to(logp, logp_ref.shape)
 
 
 def fused_sample(
@@ -203,43 +219,43 @@ def fused_sample(
     """Fused per-slot filter + categorical: one kernel, (B,) heterogeneous
     params, returns ``(tok (B,) i32, logp (B,) f32)``.
 
-    The whole (padded) vocab row sits in VMEM per grid step — fp32 rows
-    up to ~1M vocab fit the 16MB budget comfortably.  Padding columns are
-    ``NEG_INF`` so they are invisible to the filter, the softmax mass and
-    the gumbel argmax.
+    Each grid step holds one slot's (padded) vocab row in VMEM as a dense
+    (Vp/128, 128) tile — 0.6 MB at a 152k vocab — so whole-row
+    reductions run over full vregs.  Padding columns are ``NEG_INF`` so
+    they are invisible to the filter, the softmax mass and the gumbel
+    argmax.  The per-slot params ride in SMEM (scalar prefetch); the two
+    results come back replicated over one lane tile.
     """
     B, V = logits.shape
-    Vp = max(128, V + (-V % 128))
+    Vp = max(_LANES, V + (-V % _LANES))
     x = logits.astype(jnp.float32)
     if Vp != V:
         x = jnp.pad(x, ((0, 0), (0, Vp - V)), constant_values=NEG_INF)
+    x = x.reshape(B, Vp // _LANES, _LANES)
 
+    row = lambda b, *_: (b, 0, 0)  # noqa: E731
     tok, logp = pl.pallas_call(
         _sample_kernel,
-        grid=(B,),
-        in_specs=[
-            pl.BlockSpec((1, Vp), lambda b: (b, 0)),
-            pl.BlockSpec((1,), lambda b: (b,)),
-            pl.BlockSpec((1,), lambda b: (b,)),
-            pl.BlockSpec((1,), lambda b: (b,)),
-            pl.BlockSpec((1,), lambda b: (b,)),
-            pl.BlockSpec((1,), lambda b: (b,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1), lambda b: (b, 0)),
-            pl.BlockSpec((1, 1), lambda b: (b, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,  # temperature, top_k, top_p, seed, step
+            grid=(B,),
+            in_specs=[pl.BlockSpec((1, Vp // _LANES, _LANES), row)],
+            out_specs=[
+                pl.BlockSpec((1, 1, _LANES), row),
+                pl.BlockSpec((1, 1, _LANES), row),
+            ],
+        ),
         out_shape=[
-            jax.ShapeDtypeStruct((B, 1), jnp.int32),
-            jax.ShapeDtypeStruct((B, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, _LANES), jnp.int32),
+            jax.ShapeDtypeStruct((B, 1, _LANES), jnp.float32),
         ],
         interpret=interpret,
     )(
-        x,
         temperature.astype(jnp.float32),
         top_k.astype(jnp.int32),
         top_p.astype(jnp.float32),
-        seed.astype(jnp.uint32),
-        step.astype(jnp.uint32),
+        jax.lax.bitcast_convert_type(seed.astype(jnp.uint32), jnp.int32),
+        jax.lax.bitcast_convert_type(step.astype(jnp.uint32), jnp.int32),
+        x,
     )
-    return tok[:, 0], logp[:, 0]
+    return tok[:, 0, 0], logp[:, 0, 0]

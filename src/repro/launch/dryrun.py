@@ -1,10 +1,9 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 """Multi-pod dry-run: lower + compile every (arch × input-shape × mesh)
 combination against placeholder devices, and extract the roofline terms.
 
-MUST be run as its own process (the two lines above run before any other
-import so jax sees 512 host devices):
+MUST be run as its own process (run as a script, the lines below set
+XLA_FLAGS before jax is imported, so jax sees 512 host devices; importing
+the module leaves the environment alone):
 
     PYTHONPATH=src python -m repro.launch.dryrun --arch qwen2-7b \
         --shape train_4k --mesh single --out experiments/dryrun
@@ -15,6 +14,11 @@ Outputs one JSON per combination with:
   * per-collective byte totals parsed from the compiled HLO
   * derived roofline terms vs TPU v5e constants (see benchmarks/roofline.py)
 """
+import os
+
+if __name__ == "__main__":
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+
 import argparse
 import dataclasses
 import json

@@ -1,4 +1,9 @@
-"""Production meshes.
+"""Device meshes.
+
+Every mesh in the repo is built by ``make_mesh`` so its axes are
+``AxisType.Auto``: the model code places activations with
+``with_sharding_constraint`` (``parallel/sharding.py``), which JAX accepts
+only on Auto axes (``jax.make_mesh`` defaults to Explicit axes).
 
 ``make_production_mesh`` is a FUNCTION (not a module constant) so importing
 this module never touches jax device state.  Shapes:
@@ -17,15 +22,28 @@ the distributed Trainer (tests/test_trainer_distributed.py).
 """
 from __future__ import annotations
 
+from typing import Optional, Sequence
+
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str],
+              devices: Optional[Sequence] = None) -> jax.sharding.Mesh:
+    """A mesh of ``shape`` over ``axes`` with every axis Auto; ``devices``
+    defaults to all visible devices."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axes),
+        axis_types=(AxisType.Auto,) * len(axes), devices=devices,
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_test_mesh(shape=(2, 4), axes=("data", "model")):
     """Small mesh for 8-host-device integration tests."""
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)

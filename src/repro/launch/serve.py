@@ -42,6 +42,8 @@ import numpy as np
 
 from repro.configs import get_config, get_smoke_config
 from repro.core.config import ParallelConfig, ServeConfig
+from repro.launch.compile_cache import use_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.models.model import build_model
 
 
@@ -260,6 +262,7 @@ def main() -> None:
                         "into this directory (also enables the engine's "
                         "step annotations/timers)")
     a = p.parse_args()
+    use_compile_cache()
 
     cfg = get_smoke_config(a.arch) if a.smoke else get_config(a.arch)
     mesh = None
@@ -274,7 +277,7 @@ def main() -> None:
                 f"{len(jax.devices())} visible (on CPU set XLA_FLAGS="
                 f"--xla_force_host_platform_device_count={d * m})"
             )
-        mesh = jax.make_mesh((d, m), ("data", "model"))
+        mesh = make_mesh((d, m), ("data", "model"))
     model = build_model(cfg, ParallelConfig(), mesh)
     params = model.init(jax.random.PRNGKey(0))
     if a.continuous:

@@ -38,6 +38,8 @@ from repro.data.pipeline import CLMBatches, MLMBatches
 from repro.data.producer import BackgroundProducer
 from repro.data.sampler import ClusterSampler, greedy_length_clusters
 from repro.data.size_aware import SizeAwareSampler
+from repro.launch.compile_cache import use_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.models.model import build_model
 from repro.training.loop import Trainer
 
@@ -119,9 +121,9 @@ def build_mesh(spec: str):
     if spec == "none":
         return None
     if spec == "auto":
-        return jax.make_mesh((n, 1), ("data", "model")) if n > 1 else None
+        return make_mesh((n, 1), ("data", "model")) if n > 1 else None
     d, m = (int(x) for x in spec.lower().split("x"))
-    return jax.make_mesh((d, m), ("data", "model"))
+    return make_mesh((d, m), ("data", "model"))
 
 
 def main() -> None:
@@ -166,6 +168,7 @@ def main() -> None:
                    help="capture a jax.profiler trace of the run into this "
                         "directory (also enables step annotations/timers)")
     a = p.parse_args()
+    use_compile_cache()
 
     cfg = get_smoke_config(a.arch) if a.smoke else get_config(a.arch)
     tc = TrainConfig(

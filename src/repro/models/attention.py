@@ -7,6 +7,7 @@ lowers to flash-decoding-style partial-stat all-reduces, see ops.decode_attentio
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -219,13 +220,23 @@ def attention_apply(
         v = ctx.cons(v, "batch", None)
     # train / prefill hot path: cfg.kernel_impl="auto" hits the fused Pallas
     # kernels (fwd + custom-VJP bwd) on TPU, the blockwise xla path elsewhere
-    o = ops.attention(
-        q, k, v,
+    attn = functools.partial(
+        ops.attention,
         causal=causal and not is_cross,
         window=window,
         softcap=cfg.attn_logit_softcap,
         impl=cfg.kernel_impl,
     )
+    # on a mesh a Pallas kernel runs per shard: batch rows over the batch
+    # axes, heads over `model` when both q and kv heads divide it
+    qs = ctx.fit(q.shape, "batch", None, "tp", None)
+    ks = ctx.fit(k.shape, "batch", None, "tp", None)
+    if qs[2:3] != ks[2:3]:
+        qs = ctx.fit(q.shape, "batch")
+        ks = ctx.fit(k.shape, "batch")
+    attn = ctx.per_shard(attn, (qs, ks, ks), qs,
+                         when=ops.is_pallas(cfg.kernel_impl))
+    o = attn(q, k, v)
     out = _out_proj(cfg, ctx, params, o)
 
     new_cache = None

@@ -8,6 +8,7 @@ from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec
 
 from repro.core.config import ModelConfig
 from repro.core.module import P
@@ -25,11 +26,18 @@ def norm_defs(cfg: ModelConfig, d: int) -> Dict[str, P]:
     return defs
 
 
-def norm_apply(cfg: ModelConfig, params: Dict[str, Any], x: jax.Array) -> jax.Array:
-    if cfg.norm_type == "rmsnorm":
-        return ops.rmsnorm(x, params["scale"])
-    bias = params.get("bias")
-    return ops.layernorm(x, params["scale"], bias)
+def norm_apply(
+    cfg: ModelConfig, ctx: ShardingCtx, params: Dict[str, Any], x: jax.Array
+) -> jax.Array:
+    norm = ops.rmsnorm if cfg.norm_type == "rmsnorm" else ops.layernorm
+    w = tuple(params[k] for k in ("scale", "bias") if k in params)
+    # a Pallas kernel on a mesh runs per shard of batch rows
+    xs = ctx.fit(x.shape, "batch")
+    norm = ctx.per_shard(
+        norm, (xs,) + (PartitionSpec(),) * len(w), xs,
+        when=ops.is_pallas("auto"),
+    )
+    return norm(x, *w)
 
 
 # --------------------------------------------------------------------- #

@@ -11,6 +11,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec
 
 from repro.core.config import ModelConfig, ParallelConfig
 from repro.core.module import P, abstract, materialize, spec_tree
@@ -96,7 +97,7 @@ class Model:
             enc_cfg, self.ctx, params["encoder"]["layers"], x,
             mode="train", causal=False,
         )
-        return L.norm_apply(cfg, params["encoder"]["final_norm"], x)
+        return L.norm_apply(cfg, self.ctx, params["encoder"]["final_norm"], x)
 
     # ------------------------------------------------------------ backbone
     def _decoder_input(self, params, batch, mode: str) -> Tuple[jax.Array, Any]:
@@ -127,7 +128,7 @@ class Model:
             cache_pos=cache_pos, cross_kv=cross_kv, block_table=block_table,
             chunk_valid=chunk_valid,
         )
-        x = L.norm_apply(cfg, params["final_norm"], x)
+        x = L.norm_apply(cfg, self.ctx, params["final_norm"], x)
         return x, new_caches, aux
 
     def head_weight(self, params):
@@ -180,9 +181,16 @@ class Model:
         # cfg.kernel_impl="auto": fused Pallas CE (fwd + custom-VJP bwd) on
         # TPU so the (tokens × vocab) logits/grad never materialize; block-
         # wise xla elsewhere
-        losses, _ = ops.cross_entropy(
-            hidden, w_head, targets, vocab=cfg.vocab_size, impl=cfg.kernel_impl
+        # a Pallas kernel on a mesh runs per token shard, the head replicated
+        ts = self.ctx.fit(targets.shape, "tokens")
+        ce = self.ctx.per_shard(
+            functools.partial(
+                ops.cross_entropy, vocab=cfg.vocab_size, impl=cfg.kernel_impl
+            ),
+            (PartitionSpec(*ts, None), PartitionSpec(), ts), (ts, ts),
+            when=ops.is_pallas(cfg.kernel_impl),
         )
+        losses, _ = ce(hidden, w_head, targets)
         denom = jnp.maximum(mask.sum(), 1.0)
         loss = (losses * mask).sum() / denom
         metrics = {"ce_loss": loss, "aux_loss": aux, "tokens": denom}

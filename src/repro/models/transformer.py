@@ -102,7 +102,7 @@ def _apply_sublayer(
     vector for MoE models (``moe.aux_shape``), a scalar zero for dense."""
     aux = jnp.zeros(aux_shape(cfg), jnp.float32)
     new_cache: Dict[str, Any] = {}
-    h = L.norm_apply(cfg, params["norm1"], x)
+    h = L.norm_apply(cfg, ctx, params["norm1"], x)
     is_attn = cfg.is_attn_layer(li)
     if is_attn:
         mix, c = attention_apply(
@@ -143,7 +143,7 @@ def _apply_sublayer(
     x = x + mix
 
     if cross_kv is not None or (cache and "xattn" in cache):
-        hx = L.norm_apply(cfg, params["norm_x"], x)
+        hx = L.norm_apply(cfg, ctx, params["norm_x"], x)
         xmix, _ = attention_apply(
             cfg, ctx, params["xattn"], hx,
             mode=mode, cross_kv=cross_kv,
@@ -161,7 +161,7 @@ def _apply_sublayer(
             }
 
     if "ffn" in params:
-        h2 = L.norm_apply(cfg, params["norm2"], x)
+        h2 = L.norm_apply(cfg, ctx, params["norm2"], x)
         if cfg.is_moe_layer(li):
             ff_out, aux = moe_apply(cfg, ctx, params["ffn"], h2)
         else:

@@ -5,8 +5,8 @@ Three tools, all default-off and all zero-cost when off:
   * :func:`trace_ctx` — a context manager around ``jax.profiler.trace``:
     the whole serving/training run inside it lands in a TensorBoard-
     readable XPlane trace under the given directory.  No-op when the
-    directory is falsy or the profiler is unavailable (e.g. a stripped
-    CPU wheel), so launchers can pass the flag through unconditionally.
+    directory is falsy, so launchers can pass the flag through
+    unconditionally; a trace that was asked for and cannot start raises.
   * :class:`annotate` — a named ``jax.profiler.TraceAnnotation`` scope
     marking host-side regions (the jitted decode dispatch, a train
     step) so they are attributable in the trace timeline.  Constructed
@@ -27,33 +27,25 @@ import contextlib
 import time
 from typing import Dict, Iterator, Optional
 
-try:  # profiler is optional at runtime; hooks degrade to no-ops
-    from jax import profiler as _jax_profiler
-except Exception:  # noqa: BLE001 — any import failure means "unavailable"
-    _jax_profiler = None
+from jax import profiler as _jax_profiler
 
 
 @contextlib.contextmanager
 def trace_ctx(log_dir: Optional[str]) -> Iterator[None]:
     """``with trace_ctx("/tmp/prof"):`` profiles the enclosed run.
 
-    Falsy ``log_dir`` (or an unavailable/already-active profiler) makes
-    this a plain no-op, so call sites need no conditional."""
-    if not log_dir or _jax_profiler is None:
+    A falsy ``log_dir`` makes this a plain no-op, so call sites need no
+    conditional.  Otherwise a trace that cannot start (e.g. one is
+    already running) raises: a run asked to be profiled never exits 0
+    without its trace."""
+    if not log_dir:
         yield
         return
-    try:
-        _jax_profiler.start_trace(log_dir)
-    except Exception:  # noqa: BLE001 — e.g. a trace is already running
-        yield
-        return
+    _jax_profiler.start_trace(log_dir)
     try:
         yield
     finally:
-        try:
-            _jax_profiler.stop_trace()
-        except Exception:  # noqa: BLE001 — never let teardown kill the run
-            pass
+        _jax_profiler.stop_trace()
 
 
 class annotate:
@@ -65,11 +57,7 @@ class annotate:
     __slots__ = ("_ctx",)
 
     def __init__(self, name: str, enabled: bool = True) -> None:
-        self._ctx = (
-            _jax_profiler.TraceAnnotation(name)
-            if enabled and _jax_profiler is not None
-            else None
-        )
+        self._ctx = _jax_profiler.TraceAnnotation(name) if enabled else None
 
     def __enter__(self) -> "annotate":
         if self._ctx is not None:

@@ -144,6 +144,27 @@ class ShardingCtx:
             return PartitionSpec()
         return spec(self.rules, *logical)
 
+    def fit(self, shape, *logical: Optional[str]) -> PartitionSpec:
+        """``sp(*logical)`` with the axes that do not divide ``shape``
+        dropped (``fit_spec``): a spec ``per_shard`` can split by."""
+        if self.mesh is None:
+            return PartitionSpec()
+        return fit_spec(shape, self.mesh, spec(self.rules, *logical))
+
+    def per_shard(self, fn, in_specs, out_specs, *, when: bool = True):
+        """``fn`` run on each device's shard (``jax.shard_map``) on a real
+        mesh when ``when``; ``fn`` itself otherwise.  Pallas (Mosaic)
+        kernels need this: the compiler cannot partition them, so a kernel
+        on a mesh sees its local block of batch rows / heads / tokens."""
+        if not when or self.mesh is None or self.mesh.empty or self.mesh.size == 1:
+            return fn
+        # check_vma=False: a pallas_call's out_shape carries no
+        # varying-axes annotation for the checker to use
+        return jax.shard_map(
+            fn, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs,
+            check_vma=False,
+        )
+
     # ------------------------------------------------------- expert axis
     def expert_axis_size(self) -> int:
         """Product of the mesh axes the logical ``experts`` dim maps to

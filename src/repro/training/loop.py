@@ -252,19 +252,17 @@ class Trainer:
     def _build_compiled(self, batch, sig) -> Dict[str, Any]:
         """AOT-compile the sharded step for this batch shape (avoids the
         double compile of lower-after-first-call) and extract the HLO
-        roofline terms the tokens/s / MFU report uses."""
-        entry: Dict[str, Any] = {"fn": self._jit_step, "hlo": None,
-                                 "flops": 0.0}
+        roofline terms the tokens/s / MFU report uses.  A compile error
+        propagates: a step that does not compile must not run."""
+        t0 = time.perf_counter()
+        compiled = self._jit_step.lower(self.state, batch).compile()
+        entry: Dict[str, Any] = {"fn": compiled, "hlo": None, "flops": 0.0,
+                                 "compile_s": time.perf_counter() - t0}
         try:
-            compiled = self._jit_step.lower(self.state, batch).compile()
-            try:
-                from repro.launch.hlo_cost import analyze
+            from repro.launch.hlo_cost import analyze
 
-                entry["hlo"] = analyze(compiled.as_text())
-            except Exception:  # noqa: BLE001 — reporting only
-                pass
-            entry["fn"] = compiled
-        except Exception:  # noqa: BLE001 — fall back to on-dispatch compile
+            entry["hlo"] = analyze(compiled.as_text())
+        except Exception:  # noqa: BLE001 — the HLO cost report is optional
             pass
         tok = batch.get("tokens") if isinstance(batch, dict) else None
         if tok is not None and getattr(tok, "ndim", 0) >= 2:

@@ -80,7 +80,8 @@ def test_dryrun_bundle_small_mesh(arch):
         from repro.configs import get_smoke_config
         from repro.launch.shapes import InputShape, dryrun_bundle
         from repro.launch.hlo_cost import analyze
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = get_smoke_config("{arch}")
         for shp in [InputShape("t", 64, 8, "train"), InputShape("d", 64, 8, "decode")]:
             fn, args, in_sh, meta = dryrun_bundle(cfg, shp, mesh, ParallelConfig())
@@ -105,7 +106,8 @@ def test_multipod_mini_mesh():
         from repro.configs import get_smoke_config
         from repro.launch.shapes import InputShape, dryrun_bundle
         from repro.launch.hlo_cost import analyze
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         cfg = get_smoke_config("qwen2-7b")
         shp = InputShape("t", 64, 8, "train")
         pc = ParallelConfig(fsdp_axes=("pod", "data"))

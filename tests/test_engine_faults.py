@@ -470,6 +470,7 @@ _MESH_COMMON = textwrap.dedent("""
     from repro.serving.engine import Engine, Request
     from repro.serving.faults import FaultPlan
     from repro.serving.sampling import SamplingParams
+    from repro.launch.mesh import make_mesh
 
     class FakeClock:
         def __init__(self):
@@ -483,7 +484,7 @@ _MESH_COMMON = textwrap.dedent("""
                       num_heads=8, num_kv_heads=8, d_ff=128, vocab_size=64,
                       dtype="float32")
     PARAMS = build_model(CFG).init(jax.random.PRNGKey(0))
-    MESH = jax.make_mesh(__MESH__, ("data", "model"))
+    MESH = make_mesh(__MESH__, ("data", "model"))
 
     def model_for(mesh):
         return build_model(CFG, ParallelConfig(), mesh)

@@ -10,6 +10,7 @@ import pytest
 from repro.core.config import ModelConfig, ParallelConfig
 from repro.core.module import P, spec_tree
 from repro.models.model import build_model
+from repro.launch.mesh import make_mesh
 from repro.parallel.sharding import axis_rules
 
 
@@ -132,7 +133,7 @@ def test_param_specs_cover_all_leaves_and_axes_exist():
         pc = ParallelConfig()
         model = build_model(cfg)
         defs = model.param_defs()
-        rules = axis_rules(pc, jax.make_mesh((1, 1), ("data", "model")))
+        rules = axis_rules(pc, make_mesh((1, 1), ("data", "model")))
         specs = spec_tree(defs, rules)
         names = {a for s in jax.tree.leaves(
             specs, is_leaf=lambda x: isinstance(x, shd.PartitionSpec))

@@ -270,6 +270,7 @@ EP_CODE = textwrap.dedent("""
     import jax.numpy as jnp
     from repro.core.config import ModelConfig, ParallelConfig
     from repro.models.model import build_model
+    from repro.launch.mesh import make_mesh
 
     assert jax.device_count() == 8, jax.device_count()
     cfg = ModelConfig(
@@ -288,7 +289,7 @@ EP_CODE = textwrap.dedent("""
     toks_ref = [int(t) for t in jnp.argmax(logits_ref[:, -1], -1)]
 
     for shape in ((1, 8), (2, 4)):
-        mesh = jax.make_mesh(shape, ("data", "model"))
+        mesh = make_mesh(shape, ("data", "model"))
         m_sh = build_model(cfg, ParallelConfig(), mesh)
         assert m_sh.ctx.expert_parallel(cfg.num_experts) == (shape[1] in (4, 8))
         sh_params = jax.device_put(

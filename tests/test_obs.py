@@ -421,3 +421,12 @@ def test_profile_hooks_are_noops_when_disabled(tmp_path):
         pass
     with trace_ctx(str(tmp_path / "prof")):
         jax.block_until_ready(jax.numpy.ones(4) * 2)
+
+
+def test_trace_ctx_raises_when_trace_cannot_start(tmp_path):
+    """A profile that was asked for and cannot start fails the run
+    instead of exiting 0 without a trace (here: one already running)."""
+    with trace_ctx(str(tmp_path / "outer")):
+        with pytest.raises(RuntimeError):
+            with trace_ctx(str(tmp_path / "inner")):
+                pass

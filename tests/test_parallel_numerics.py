@@ -14,6 +14,7 @@ CODE = textwrap.dedent("""
     from repro.core.config import ModelConfig, ParallelConfig, TrainConfig
     from repro.models.model import build_model
     from repro.training.train_step import init_train_state, make_train_step
+    from repro.launch.mesh import make_mesh
 
     cfg = ModelConfig(
         name="t", family="dense", num_layers=2, d_model=64, num_heads=8,
@@ -35,7 +36,7 @@ CODE = textwrap.dedent("""
         return float(m["loss"]), float(m["grad_norm"])
 
     ref = loss_with(None, ParallelConfig())
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     tp = loss_with(mesh, ParallelConfig(attention_parallelism="head_tp"))
     cp = loss_with(mesh, ParallelConfig(attention_parallelism="context"))
     print("ref", ref); print("tp", tp); print("cp", cp)

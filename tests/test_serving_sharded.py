@@ -47,6 +47,7 @@ _COMMON = textwrap.dedent("""
     from repro.models.model import build_model
     from repro.serving.engine import Engine, Request
     from repro.serving.sampling import SamplingParams
+    from repro.launch.mesh import make_mesh
 
     CFG = ModelConfig(name="smoke", family="dense", num_layers=2,
                       d_model=64, num_heads=8, num_kv_heads=8, d_ff=128,
@@ -78,7 +79,7 @@ _PARITY = _COMMON + textwrap.dedent("""
         "prefix+chunk": dict(cache_layout="paged", page_size=8,
                              prefix_cache=True, prefill_chunk=8),
     }
-    mesh = jax.make_mesh(__MESH__, ("data", "model"))
+    mesh = make_mesh(__MESH__, ("data", "model"))
     for name, kw in LAYOUTS.items():
         ref = serve(None, **kw)
         got = serve(mesh, **kw)
@@ -97,7 +98,7 @@ def test_mesh_decode_single_bulk_transfer():
     """Steady-state sharded decode keeps the one-device_get-per-step
     contract: no host->device uploads, exactly one bulk download."""
     code = _COMMON + textwrap.dedent("""
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         eng = make_engine(mesh, cache_layout="paged", page_size=8)
         for i, p in enumerate(PROMPTS[:3]):
             eng.submit(Request(uid=i, prompt=p, max_new=16))
@@ -125,8 +126,9 @@ _CHURN = textwrap.dedent("""
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec
     from test_prefix_cache import Churn, PAGE
+    from repro.launch.mesh import make_mesh
 
-    MESH = jax.make_mesh((1, 8), ("data", "model"))
+    MESH = make_mesh((1, 8), ("data", "model"))
     HKV, D = 8, 4
 
     class ShardedChurn(Churn):
@@ -225,7 +227,7 @@ def test_cache_shardings_shard_kv_over_model_axis():
     tensor-parallel serving): each device holds 1/model-axis of the pool,
     while block tables / pos stay replicated for host-side paging."""
     code = _COMMON + textwrap.dedent("""
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         eng = make_engine(mesh, cache_layout="paged", page_size=8)
         k_pool = eng.cache["layers"]["sub0"]["attn"]["k_pool"]
         shard_shape = k_pool.sharding.shard_shape(k_pool.shape)

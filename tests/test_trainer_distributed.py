@@ -255,6 +255,7 @@ CODE = textwrap.dedent("""
     from repro.training.loop import Trainer
     from repro.training import train_step as TS
     from repro.checkpoint import ckpt
+    from repro.launch.mesh import make_mesh
 
     assert jax.device_count() == 8, jax.device_count()
     cfg = ModelConfig(name="t", family="dense", num_layers=2, d_model=64,
@@ -269,7 +270,7 @@ CODE = textwrap.dedent("""
 
     # (a) sharded loss/grad-norm trajectory matches single-device
     _, h_ref = Trainer(build_model(cfg), tc, verbose=False).run(pipe())
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     m_sh = build_model(cfg, ParallelConfig(), mesh)
     tr_sh = Trainer(m_sh, tc, verbose=False)
     state_sh, h_sh = tr_sh.run(pipe())
@@ -281,7 +282,7 @@ CODE = textwrap.dedent("""
     # (d) checkpoint saved on (2,4) restores onto (4,2): identical leaves
     ckdir = tmp + "/ck"
     tr_sh.save(ckdir)
-    mesh2 = jax.make_mesh((4, 2), ("data", "model"))
+    mesh2 = make_mesh((4, 2), ("data", "model"))
     m2 = build_model(cfg, ParallelConfig(), mesh2)
     st2, step2, extra = ckpt.restore_train_state(
         ckdir, TS.abstract_train_state(m2), TS.state_shardings(m2))
@@ -370,3 +371,16 @@ def test_trainer_aborts_after_consecutive_nonfinite(tmp_path):
     assert ei.value.skips == 3
     assert ei.value.step == 2  # steps 0,1,2 skipped -> streak hits 3 at 2
     assert tr.skipped_total == 3
+
+
+def test_trainer_compile_error_propagates(tmp_path, monkeypatch):
+    """A train step that does not compile fails the run; the Trainer
+    never falls back to compiling on dispatch."""
+    tr = Trainer(build_model(tiny_cfg()), tiny_tc(), verbose=False)
+
+    def refuse(*a, **k):
+        raise RuntimeError("compiler refused the step")
+
+    monkeypatch.setattr(tr._jit_step, "lower", refuse)
+    with pytest.raises(RuntimeError, match="compiler refused"):
+        tr.run(clm_pipeline(tmp_path))
