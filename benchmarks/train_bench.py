@@ -58,12 +58,7 @@ def _run_one(report, accum: int) -> None:
     report(
         f"train_step_accum{accum}",
         last["step_time"] * 1e6,
-        f"tok/s={last['tokens_per_sec']:.0f}"
-        + (
-            f" flop_ratio={last['useful_flop_ratio']:.2f}"
-            if "useful_flop_ratio" in last
-            else ""
-        ),
+        f"tok/s={last['tokens_per_sec']:.0f}",
     )
 
 

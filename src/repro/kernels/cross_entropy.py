@@ -164,6 +164,7 @@ def fused_cross_entropy(
             pltpu.VMEM((block_t, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="cross_entropy_fwd",
     )(hidden_p, w_p, tgt_p)
     return loss[:T, 0], lse[:T, 0]
 
@@ -295,6 +296,7 @@ def fused_cross_entropy_bwd(
         out_shape=jax.ShapeDtypeStruct((Tp, D), hidden.dtype),
         scratch_shapes=[pltpu.VMEM((block_t, D), jnp.float32)],
         interpret=interpret,
+        name="cross_entropy_dh",
     )(hidden, w_pad, targets, lse, gl, glse)
 
     dw_kernel = functools.partial(
@@ -316,5 +318,6 @@ def fused_cross_entropy_bwd(
         out_shape=jax.ShapeDtypeStruct((D, Vpp), w_out.dtype),
         scratch_shapes=[pltpu.VMEM((D, block_v), jnp.float32)],
         interpret=interpret,
+        name="cross_entropy_dw",
     )(hidden, w_pad, targets, lse, gl, glse)
     return dh[:T], dw[:, :Vp]

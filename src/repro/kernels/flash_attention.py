@@ -239,6 +239,7 @@ def flash_attention_fwd(
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_fwd",
     )(qh, kh, vh)
     out, lse = out[:, :S], lse[:, :S, 0]
     return jnp.transpose(out.reshape(B, H, S, D), (0, 2, 1, 3)), lse
@@ -492,6 +493,7 @@ def flash_attention_bwd(
         out_shape=jax.ShapeDtypeStruct((B * H, Sp, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         interpret=interpret,
+        name="flash_attention_dq",
     )(qh, kh, vh, doh, lse, delta)
 
     # ---- dK/dV: grid (B·Hkv, kv, group·q) — the innermost dim walks every
@@ -546,6 +548,7 @@ def flash_attention_bwd(
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_dkv",
     )(qh, doh, lse, delta, kh, vh)
 
     dq = jnp.transpose(dq[:, :S].reshape(B, H, S, D), (0, 2, 1, 3))

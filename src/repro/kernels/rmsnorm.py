@@ -61,6 +61,7 @@ def rmsnorm(x: jax.Array, w: jax.Array, eps: float = 1e-5, *, interpret: bool = 
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, d), x.dtype),
         interpret=interpret,
+        name="rmsnorm",
     )(xf, w)
     return out.reshape(orig_shape)
 
@@ -91,5 +92,6 @@ def layernorm(
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, d), x.dtype),
         interpret=interpret,
+        name="layernorm",
     )(xf, w, bb)
     return out.reshape(orig_shape)

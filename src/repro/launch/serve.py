@@ -24,7 +24,9 @@ while serving (default 64 — a wedged engine is visible as the watchdog
 climbs, not only at exit); ``--metrics-dir DIR`` refreshes a Prometheus
 exposition + JSON snapshot there on the same cadence; ``--trace PATH``
 writes the request-lifecycle JSONL at exit; ``--profile DIR`` captures
-a ``jax.profiler`` trace of the whole serving run.
+a ``jax.profiler`` trace of the whole serving run.  The engine's spans
+(``engine.prefill``, ``engine.decode``, ``engine.host_sync``) are always
+recorded in the step log, and their means are printed at exit.
 
 The static-batch path (``generate``) remains for encoder-decoder /
 vision-frontend archs the slot engine does not admit; it is a deprecated
@@ -110,17 +112,16 @@ def _health_line(h) -> str:
 def serve_continuous(model, params, sc: ServeConfig, *, gen: int,
                      prompt_len: int, requests: int,
                      health_every: int = 0, metrics_dir: str = "",
-                     trace_path: str = "", profile: bool = False) -> None:
+                     trace_path: str = "") -> None:
     """Drive the continuous-batching engine through the LLM facade.
 
     Telemetry: ``health_every=N`` prints the health snapshot every N
     engine steps WHILE serving (a stall is visible as the watchdog
     climbs, not just in the exit summary) and, with ``metrics_dir``,
     refreshes the Prometheus exposition + JSON snapshot there on the
-    same cadence.  ``trace_path`` writes the lifecycle JSONL at exit;
-    ``profile`` turns on the jax.profiler annotations around the jitted
-    prefill/decode dispatches."""
-    from repro.obs import MetricsRegistry, TraceRecorder
+    same cadence.  ``trace_path`` writes the lifecycle JSONL at exit.
+    The step log's per-span means are printed at exit."""
+    from repro.obs import STEP_LOG, MetricsRegistry, TraceRecorder
     from repro.serving.api import LLM
     from repro.serving.sampling import SamplingParams
 
@@ -145,7 +146,6 @@ def serve_continuous(model, params, sc: ServeConfig, *, gen: int,
     cfg = model.cfg
     rng = np.random.default_rng(0)
     llm = LLM.from_config(model, params, sc, metrics=reg, trace=tracer,
-                          profile=profile,
                           on_step=_on_step if health_every else None)
     # a shared task preamble on half the requests exercises the prefix
     # cache the way protein/chemistry serving does (fixed scaffolds);
@@ -200,10 +200,9 @@ def serve_continuous(model, params, sc: ServeConfig, *, gen: int,
         print(f"  trace: {len(tracer)} lifecycle events -> {trace_path}"
               + (f" ({tracer.dropped} older events dropped)"
                  if tracer.dropped else ""))
-    if profile and eng.step_timer is not None and eng.step_timer.totals:
-        print("  step timer:")
-        for line in eng.step_timer.report().splitlines():
-            print(f"    {line}")
+    print("  step log:")
+    for line in STEP_LOG.report("engine").splitlines():
+        print(f"    {line}")
 
 
 def main() -> None:
@@ -259,8 +258,7 @@ def main() -> None:
                         "path at exit")
     p.add_argument("--profile", default="",
                    help="capture a jax.profiler trace of the serving run "
-                        "into this directory (also enables the engine's "
-                        "step annotations/timers)")
+                        "into this directory")
     a = p.parse_args()
     use_compile_cache()
 
@@ -298,8 +296,7 @@ def main() -> None:
                              prompt_len=a.prompt_len, requests=a.requests,
                              health_every=a.health_every,
                              metrics_dir=a.metrics_dir,
-                             trace_path=a.trace_path,
-                             profile=bool(a.profile))
+                             trace_path=a.trace_path)
         return
     rng = np.random.default_rng(0)
     batch = {

@@ -15,7 +15,10 @@ single-device path.
 Telemetry: ``--metrics-dir DIR`` feeds the unified registry
 (``repro.obs``) and refreshes a Prometheus exposition + JSON snapshot
 there at every log flush; ``--profile DIR`` captures a ``jax.profiler``
-trace of the whole run and prints the host-side per-phase step timer.
+trace of the whole run.  The trainer's spans (``train.data``,
+``train.compile``, ``train.dispatch``, ``train.flush``,
+``train.checkpoint``) are always recorded in the step log, and their
+means are printed at exit.
 """
 from __future__ import annotations
 
@@ -166,7 +169,7 @@ def main() -> None:
                         "here (refreshed every log flush via a trainer hook)")
     p.add_argument("--profile", default="",
                    help="capture a jax.profiler trace of the run into this "
-                        "directory (also enables step annotations/timers)")
+                        "directory")
     a = p.parse_args()
     use_compile_cache()
 
@@ -203,7 +206,7 @@ def main() -> None:
     if resume == "auto":
         resume = ckpt.latest_step(a.ckpt_dir) or ""
         print(f"resume: {resume or '(no checkpoint found — cold start)'}")
-    from repro.obs import MetricsRegistry, trace_ctx
+    from repro.obs import STEP_LOG, MetricsRegistry, trace_ctx
 
     reg = MetricsRegistry() if a.metrics_dir else None
     hooks = []
@@ -219,18 +222,16 @@ def main() -> None:
             _reg.dump_json(os.path.join(_dir, "train_metrics.json"))
 
         hooks.append(_dump)
-    trainer = Trainer(model, tc, hooks=hooks, metrics=reg,
-                      profile=bool(a.profile))
+    trainer = Trainer(model, tc, hooks=hooks, metrics=reg)
     try:
         with trace_ctx(a.profile):
             state, history = trainer.run(batches, resume_from=resume or None)
     finally:
         if hasattr(batches, "close"):
             batches.close()
-    if a.profile and trainer.step_timer is not None:
-        print("step timer:")
-        for line in trainer.step_timer.report().splitlines():
-            print(f"  {line}")
+    print("step log:")
+    for line in STEP_LOG.report("train").splitlines():
+        print(f"  {line}")
     if a.history_out:
         with open(a.history_out, "w") as f:
             json.dump(history, f, indent=1)

@@ -17,6 +17,7 @@ from repro.core.config import ModelConfig
 from repro.core.module import P
 from repro.kernels import ops
 from repro.models.layers import rope
+from repro.obs.profile import scoped
 from repro.parallel.sharding import ShardingCtx
 
 
@@ -69,6 +70,7 @@ def _out_proj(cfg, ctx: ShardingCtx, params, o: jax.Array) -> jax.Array:
     return out
 
 
+@scoped("attention")
 def attention_apply(
     cfg: ModelConfig,
     ctx: ShardingCtx,

@@ -13,6 +13,7 @@ from jax.sharding import PartitionSpec
 from repro.core.config import ModelConfig
 from repro.core.module import P
 from repro.kernels import ops
+from repro.obs.profile import scoped
 from repro.parallel.sharding import ShardingCtx
 
 
@@ -26,6 +27,7 @@ def norm_defs(cfg: ModelConfig, d: int) -> Dict[str, P]:
     return defs
 
 
+@scoped("norm")
 def norm_apply(
     cfg: ModelConfig, ctx: ShardingCtx, params: Dict[str, Any], x: jax.Array
 ) -> jax.Array:
@@ -86,6 +88,7 @@ def _act(name: str, x: jax.Array) -> jax.Array:
     return jax.nn.relu(x)
 
 
+@scoped("ffn")
 def mlp_apply(
     cfg: ModelConfig, ctx: ShardingCtx, params: Dict[str, Any], x: jax.Array
 ) -> jax.Array:
@@ -120,6 +123,7 @@ def embedding_defs(cfg: ModelConfig) -> Dict[str, P]:
     return defs
 
 
+@scoped("embed")
 def embed_apply(
     cfg: ModelConfig,
     ctx: ShardingCtx,

@@ -19,6 +19,7 @@ from repro.core.precision import policy_for
 from repro.kernels import ops
 from repro.models import layers as L
 from repro.models import transformer as T
+from repro.obs.profile import scoped
 from repro.parallel.sharding import ShardingCtx, fit_spec, null_ctx
 from repro.parallel.sharding import spec as axis_spec
 
@@ -134,6 +135,7 @@ class Model:
     def head_weight(self, params):
         return L.lm_head_weight(self.cfg, params["head"], params["embed"])
 
+    @scoped("head")
     def logits(self, params, hidden: jax.Array) -> jax.Array:
         cfg = self.cfg
         w = self.head_weight(params).astype(hidden.dtype)
@@ -177,22 +179,25 @@ class Model:
                 else jnp.ones_like(targets, jnp.float32)
             )
 
-        w_head = self.head_weight(params).astype(self.policy.cdt)
-        # cfg.kernel_impl="auto": fused Pallas CE (fwd + custom-VJP bwd) on
-        # TPU so the (tokens × vocab) logits/grad never materialize; block-
-        # wise xla elsewhere
-        # a Pallas kernel on a mesh runs per token shard, the head replicated
-        ts = self.ctx.fit(targets.shape, "tokens")
-        ce = self.ctx.per_shard(
-            functools.partial(
-                ops.cross_entropy, vocab=cfg.vocab_size, impl=cfg.kernel_impl
-            ),
-            (PartitionSpec(*ts, None), PartitionSpec(), ts), (ts, ts),
-            when=ops.is_pallas(cfg.kernel_impl),
-        )
-        losses, _ = ce(hidden, w_head, targets)
-        denom = jnp.maximum(mask.sum(), 1.0)
-        loss = (losses * mask).sum() / denom
+        with jax.named_scope("head"):
+            w_head = self.head_weight(params).astype(self.policy.cdt)
+            # cfg.kernel_impl="auto": fused Pallas CE (fwd + custom-VJP bwd)
+            # on TPU so the (tokens × vocab) logits/grad never materialize;
+            # blockwise xla elsewhere
+            # a Pallas kernel on a mesh runs per token shard, the head
+            # replicated
+            ts = self.ctx.fit(targets.shape, "tokens")
+            ce = self.ctx.per_shard(
+                functools.partial(
+                    ops.cross_entropy, vocab=cfg.vocab_size,
+                    impl=cfg.kernel_impl,
+                ),
+                (PartitionSpec(*ts, None), PartitionSpec(), ts), (ts, ts),
+                when=ops.is_pallas(cfg.kernel_impl),
+            )
+            losses, _ = ce(hidden, w_head, targets)
+            denom = jnp.maximum(mask.sum(), 1.0)
+            loss = (losses * mask).sum() / denom
         metrics = {"ce_loss": loss, "aux_loss": aux, "tokens": denom}
         if cfg.num_experts:
             # aux is the layer-summed router stats vector (moe.aux_shape):

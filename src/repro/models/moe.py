@@ -46,6 +46,7 @@ from repro.core.config import ModelConfig
 from repro.core.module import P
 from repro.kernels import ops
 from repro.models.layers import _act, mlp_apply, mlp_defs
+from repro.obs.profile import scoped
 from repro.parallel.sharding import ShardingCtx
 
 AUX_BASE = 4  # [lb_loss, entropy_deficit, dropped_slots, total_slots]
@@ -160,6 +161,7 @@ def _moe_expert_parallel(cfg, ctx, params, xf, flat_e, rank, keep, gates,
     return out.at[tok].add(y_slot.astype(jnp.float32) * gates[:, None])
 
 
+@scoped("ffn")
 def moe_apply(
     cfg: ModelConfig,
     ctx: ShardingCtx,

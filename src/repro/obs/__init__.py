@@ -1,7 +1,8 @@
-"""Unified telemetry: metrics registry, lifecycle tracing, profiling hooks.
+"""Unified telemetry: metrics registry, lifecycle tracing, step log.
 
-See ``obs/README.md`` for the metric catalog, trace event schema, and
-the launcher knobs (``--metrics-dir``, ``--trace``, ``--profile``)."""
+See ``obs/README.md`` for the metric catalog, trace event schema, the
+step log's spans and scopes, and the launcher knobs (``--metrics-dir``,
+``--trace``, ``--profile``)."""
 from repro.obs.metrics import (
     LATENCY_BUCKETS,
     Counter,
@@ -9,7 +10,14 @@ from repro.obs.metrics import (
     Histogram,
     MetricsRegistry,
 )
-from repro.obs.profile import StepTimer, annotate, trace_ctx
+from repro.obs.profile import (
+    SCOPES,
+    STEP_LOG,
+    StepLog,
+    scope_map,
+    span,
+    trace_ctx,
+)
 from repro.obs.trace import EVENTS, TraceRecorder
 
 __all__ = [
@@ -18,8 +26,11 @@ __all__ = [
     "Gauge",
     "Histogram",
     "MetricsRegistry",
-    "StepTimer",
-    "annotate",
+    "SCOPES",
+    "STEP_LOG",
+    "StepLog",
+    "scope_map",
+    "span",
     "trace_ctx",
     "EVENTS",
     "TraceRecorder",

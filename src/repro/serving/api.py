@@ -94,7 +94,7 @@ class LLM:
                  extra_batch: Optional[Dict[str, Any]] = None,
                  default_params: Optional[SamplingParams] = None,
                  metrics: Optional[Any] = None, trace: Optional[Any] = None,
-                 profile: bool = False, on_step: Optional[Any] = None):
+                 on_step: Optional[Any] = None):
         self.engine = Engine(
             model, params, slots=slots, max_len=max_len,
             extra_batch=extra_batch, cache_layout=cache_layout,
@@ -102,7 +102,7 @@ class LLM:
             bucket_prompts=bucket_prompts, prefix_cache=prefix_cache,
             prefill_chunk=prefill_chunk, max_queue=max_queue,
             preempt=preempt, faults=faults,
-            metrics=metrics, trace=trace, profile=profile, on_step=on_step,
+            metrics=metrics, trace=trace, on_step=on_step,
         )
         self.default_params = default_params or SamplingParams()
         self._uid = 0
@@ -114,7 +114,7 @@ class LLM:
                     **kw) -> "LLM":
         """Build from a ``ServeConfig`` — its sampling knobs (temperature,
         top_k, top_p, seed) become the default ``SamplingParams``.  Extra
-        keyword args (``metrics``, ``trace``, ``profile``, ``on_step``)
+        keyword args (``metrics``, ``trace``, ``on_step``)
         pass through to the constructor."""
         return cls(
             model, params,

@@ -122,9 +122,11 @@ fault run yields a byte-identical JSONL trace.  Both hooks are
 host-side appends on paths the engine already walks: the
 one-bulk-transfer-per-step contract is unchanged (transfer-guard
 asserted in ``tests/test_obs.py``) and the measured tok/s overhead is
-bounded <2% in ``benchmarks/serving_bench.py``.  ``profile=True`` wraps
-the jitted prefill/decode dispatches in ``jax.profiler`` annotations
-and accumulates per-phase host timings in ``Engine.step_timer``;
+bounded <2% in ``benchmarks/serving_bench.py``.  Every ``step()`` is a
+record of the step log (``repro.obs.STEP_LOG``, kind ``"engine"``) with
+host spans ``engine.prefill`` (each prompt's prefill dispatch),
+``engine.decode`` (the fused decode dispatch) and ``engine.host_sync``
+(its one bulk ``device_get``), always on and host-side only;
 ``on_step`` is a per-step callback the launchers use for periodic
 health/exposition emission.
 
@@ -168,7 +170,7 @@ from repro.serving.paged_cache import (
     write_slot_paged,
 )
 from repro.obs.metrics import LATENCY_BUCKETS, MetricsRegistry
-from repro.obs.profile import StepTimer, annotate
+from repro.obs.profile import STEP_LOG, span
 from repro.obs.trace import TraceRecorder
 from repro.serving.sampling import SamplingParams, StopChecker, effective_params
 
@@ -261,7 +263,6 @@ class Engine:
                  clock: Callable[[], float] = time.time,
                  metrics: Optional[MetricsRegistry] = None,
                  trace: Optional[TraceRecorder] = None,
-                 profile: bool = False,
                  on_step: Optional[Callable[["Engine"], None]] = None):
         self.model = model
         self.params = params
@@ -389,8 +390,6 @@ class Engine:
         # all host-side appends, nothing touches the device hot loop.
         self.metrics = metrics
         self.trace = trace
-        self.profile = bool(profile)
-        self.step_timer = StepTimer() if self.profile else None
         self.on_step = on_step
         if metrics is not None:
             fam = metrics.counter(
@@ -1064,7 +1063,7 @@ class Engine:
             for k, v in self.extra.items():
                 batch[k] = v
             Lx = L + self.n_front          # valid decoder-input tokens
-            with annotate("engine/prefill", enabled=self.profile):
+            with span("engine.prefill"):
                 logits, one_cache = self._prefill(self.params, batch, Lx)
             if self.alloc is not None:
                 pages = self.alloc.alloc(slot, need)
@@ -1235,8 +1234,13 @@ class Engine:
         Lifecycle order: queued deadline expiry -> admission (possibly
         preempting) -> prefill chunks -> lockstep decode + quarantine ->
         in-flight deadline expiry (the "next step boundary" of the
-        deadline contract) -> watchdog accounting."""
+        deadline contract) -> watchdog accounting.  The step is a record
+        of the step log (module docstring)."""
         self.steps += 1
+        with STEP_LOG.step("engine", self.steps):
+            return self._step()
+
+    def _step(self) -> int:
         self._progress = False
         done0 = len(self.done)
         self._expire_queued()
@@ -1264,21 +1268,13 @@ class Engine:
                 v = np.zeros((self.B,), bool)
                 v[bad_slots] = True
                 inject = jnp.asarray(v)
-            if self.step_timer is not None:
-                with self.step_timer.span("decode"), \
-                        annotate("engine/decode", enabled=True):
-                    tok_d, logp_d, bad_d, self.cache, self._samp = \
-                        self._decode(self.params, self.cache,
-                                     self._last_tok, self._samp, inject)
-                with self.step_timer.span("host_sync"):
-                    self._last_tok = tok_d
-                    nxt, logps, bads = jax.device_get((tok_d, logp_d, bad_d))
-            else:
+            with span("engine.decode"):
                 tok_d, logp_d, bad_d, self.cache, self._samp = self._decode(
                     self.params, self.cache, self._last_tok, self._samp,
                     inject
                 )
-                self._last_tok = tok_d
+            self._last_tok = tok_d
+            with span("engine.host_sync"):
                 nxt, logps, bads = jax.device_get((tok_d, logp_d, bad_d))
             emitted = 0
             for s in active:

@@ -4,8 +4,8 @@
 
   * sharded step — ``make_sharded_train_step`` (jit with state/batch
     in_shardings, state out_shardings, donated state), compiled ONCE ahead
-    of time; the compiled HLO feeds the tokens/s + MFU report through
-    ``launch/hlo_cost.analyze``
+    of time per batch shape; each compiled program is registered with the
+    step log, which reads its scope map from the HLO only when asked
   * batch placement — host pipeline batches land on the mesh's ``data``
     axes (``jax.make_array_from_process_local_data`` when running
     multi-process, a sharded ``device_put`` on one host)
@@ -14,15 +14,21 @@
   * async metrics — per-step metrics stay on device; ONE bulk
     ``jax.device_get`` per log interval and no implicit transfers in the
     steady state (transfer-guard tested like the serving engine)
+  * step log — every ``step()`` is a record of ``repro.obs.STEP_LOG``
+    (a ``StepTraceAnnotation("train", step_num=i)`` on the profiler's
+    clock) with host spans ``train.data`` (``next`` of the prefetcher:
+    host pipeline plus placement), ``train.compile`` (and a ``compiles``
+    counter), ``train.dispatch`` (the compiled call: enqueue only, the
+    device runs on), ``train.flush`` (the log flush's bulk
+    ``device_get``) and ``train.checkpoint``.  Always on; host-side
+    only, so the transfer contract below is untouched
   * unified telemetry — pass ``metrics=MetricsRegistry()`` (``repro.obs``)
     and the log-interval flush also feeds the shared registry
-    (tokens/s, step-time histogram, grad-norm, loss, skipped-step
-    counters): the serving engine and the trainer then report through
-    one exposition surface.  Registry writes consume only the values
-    the flush already fetched, so the transfer contract is untouched.
-    ``profile=True`` wraps the jitted step dispatch in a
-    ``jax.profiler`` annotation and accumulates host-side per-phase
-    timings in ``Trainer.step_timer``
+    (loss positions/s, step-time histogram, grad-norm, loss, skipped
+    steps, and from the step log the data wait and compiles): the
+    serving engine and the trainer then report through one exposition
+    surface.  Registry writes consume only the values the flush already
+    fetched, so the transfer contract is untouched
   * resumable checkpoints — the FULL TrainState (params + AdamW moments +
     optimizer step) plus the data-iterator cursor; ``resume_from``
     reproduces the uninterrupted run bit-exactly
@@ -33,6 +39,7 @@
 from __future__ import annotations
 
 import collections
+import itertools
 import os
 import time
 from typing import Any, Callable, Dict, Iterator, List, Optional
@@ -44,9 +51,12 @@ from repro.checkpoint import ckpt
 from repro.core.config import TrainConfig
 from repro.models.model import Model
 from repro.obs.metrics import MetricsRegistry
-from repro.obs.profile import StepTimer, annotate
+from repro.obs.profile import STEP_LOG, scope_map, span
 from repro.training import train_step as TS
 from repro.training.train_step import TrainState
+
+# keys of compiled step programs in the step log, unique in the process
+_PROGRAM_IDS = itertools.count()
 
 
 class NonFiniteLossError(RuntimeError):
@@ -125,31 +135,25 @@ class Trainer:
         *,
         hooks: Optional[List[Callable[[int, Dict[str, float]], None]]] = None,
         verbose: bool = True,
-        peak_flops: Optional[float] = None,
         prefetch: int = 2,
         metrics: Optional[MetricsRegistry] = None,
-        profile: bool = False,
     ):
         self.model, self.tc = model, tc
         mesh = model.ctx.mesh
         self.mesh = None if (mesh is None or mesh.empty or mesh.size == 1) else mesh
         self.hooks = list(hooks or [])
         self.verbose = verbose
-        self.peak_flops = peak_flops or float(
-            os.environ.get("REPRO_PEAK_FLOPS", "0")
-        ) or None
         self.prefetch = max(int(prefetch), 1)
         self._jit_step = TS.make_sharded_train_step(model, tc)
         # per-shape compile cache: size-aware batching yields a bounded
         # set of (rows, len) shapes (one per length bucket); each shape
         # AOT-compiles once and is reused, never recompiled per step
         self._compiled: Dict[Any, Dict[str, Any]] = {}
-        self.hlo_cost: Optional[Dict[str, Any]] = None  # per-device, one step
-        self._model_flops = 0.0                         # global, one step
         self.state: Optional[TrainState] = None
         self.step_idx = 0            # optimizer steps completed
         self.history: List[Dict[str, float]] = []
         self._pending: List[Dict] = []  # device metrics since last log
+        self._records: List = []        # step-log records since last log
         self._tokens_seen = 0.0
         # non-finite-step guard (see train_step.py): totals and the
         # current consecutive-skip streak, advanced at each log flush
@@ -162,14 +166,22 @@ class Trainer:
         # log-interval flush from values the ONE bulk device_get already
         # fetched — no extra transfers, no per-step host work
         self.metrics = metrics
-        self.profile = bool(profile)
-        self.step_timer = StepTimer() if self.profile else None
         if metrics is not None:
             self._c_steps = metrics.counter(
                 "train_steps_total", "optimizer steps completed"
             )
             self._c_tokens = metrics.counter(
-                "train_tokens_total", "non-pad tokens consumed"
+                "train_tokens_total",
+                "loss positions consumed (under MLM the masked positions)",
+            )
+            self._c_wait = metrics.counter(
+                "train_data_wait_seconds_total",
+                "host seconds Trainer.step waited for its next device "
+                "batch (span train.data)",
+            )
+            self._c_compiles = metrics.counter(
+                "train_compiles_total",
+                "step programs compiled (span train.compile)",
             )
             self._c_skipped = metrics.counter(
                 "train_skipped_steps_total",
@@ -183,7 +195,8 @@ class Trainer:
                 for name, help in (
                     ("loss", "last flushed total loss"),
                     ("grad_norm", "last flushed global gradient norm"),
-                    ("tokens_per_sec", "interval throughput"),
+                    ("tokens_per_sec",
+                     "loss positions per second over the log interval"),
                     ("lr", "current learning rate"),
                     ("aux_loss", "router load-balance loss (MoE)"),
                     ("router_entropy", "mean router entropy (MoE)"),
@@ -251,72 +264,66 @@ class Trainer:
 
     def _build_compiled(self, batch, sig) -> Dict[str, Any]:
         """AOT-compile the sharded step for this batch shape (avoids the
-        double compile of lower-after-first-call) and extract the HLO
-        roofline terms the tokens/s / MFU report uses.  A compile error
+        double compile of lower-after-first-call) and register it with
+        the step log under a key of its own; the log reads the program's
+        scope map from its HLO text only when asked.  A compile error
         propagates: a step that does not compile must not run."""
         t0 = time.perf_counter()
         compiled = self._jit_step.lower(self.state, batch).compile()
-        entry: Dict[str, Any] = {"fn": compiled, "hlo": None, "flops": 0.0,
+        key = f"train.{next(_PROGRAM_IDS)}"
+        STEP_LOG.add_program(key, lambda: scope_map(compiled.as_text()))
+        entry: Dict[str, Any] = {"fn": compiled, "key": key,
                                  "compile_s": time.perf_counter() - t0}
-        try:
-            from repro.launch.hlo_cost import analyze
-
-            entry["hlo"] = analyze(compiled.as_text())
-        except Exception:  # noqa: BLE001 — the HLO cost report is optional
-            pass
-        tok = batch.get("tokens") if isinstance(batch, dict) else None
-        if tok is not None and getattr(tok, "ndim", 0) >= 2:
-            # model-FLOPs convention: 6 · active params · processed tokens
-            entry["flops"] = (
-                6.0
-                * self.model.cfg.active_param_count()
-                * tok.shape[0]
-                * tok.shape[1]
-            )
         self._compiled[sig] = entry
         return entry
 
     # ------------------------------------------------------------ stepping
     def step(self) -> int:
         """One optimizer step: pull a prefetched device batch, run the
-        sharded step, stash device metrics; log/checkpoint on schedule."""
-        batch = next(self._it)
-        sig = self._batch_sig(batch)
-        entry = self._compiled.get(sig)
-        if entry is None:
-            entry = self._build_compiled(batch, sig)
-        # MFU/roofline terms follow the shape actually stepped
-        self._model_flops = entry["flops"]
-        self.hlo_cost = entry["hlo"]
-        fn = entry["fn"]
-        if self.step_timer is not None:
-            with self.step_timer.span("train_step"), \
-                    annotate("train/step", enabled=True):
-                self.state, metrics = fn(self.state, batch)
-        else:
-            self.state, metrics = fn(self.state, batch)
+        sharded step, stash device metrics; log/checkpoint on schedule.
+        The step and its phases are spans of the step log (module
+        docstring)."""
         s = self.step_idx
-        self.step_idx = s + 1
-        self._pending.append(metrics)
-        if (s % max(self.tc.log_every, 1)) == 0 or s == self.tc.total_steps - 1:
-            self._flush_log(s)
-        if (
-            self.tc.ckpt_every
-            and self.tc.ckpt_dir
-            and self.step_idx % self.tc.ckpt_every == 0
-        ):
-            self.save(
-                os.path.join(self.tc.ckpt_dir, f"step_{self.step_idx}")
-            )
+        with STEP_LOG.step("train", s) as rec:
+            with span("train.data"):
+                batch = next(self._it)
+            sig = self._batch_sig(batch)
+            entry = self._compiled.get(sig)
+            if entry is None:
+                with span("train.compile"):
+                    entry = self._build_compiled(batch, sig)
+                STEP_LOG.count("compiles")
+            rec.program = entry["key"]
+            with span("train.dispatch"):
+                self.state, metrics = entry["fn"](self.state, batch)
+            self.step_idx = s + 1
+            self._pending.append(metrics)
+            self._records.append(rec)
+            if (s % max(self.tc.log_every, 1)) == 0 or s == self.tc.total_steps - 1:
+                with span("train.flush"):
+                    self._flush_log(s)
+            if (
+                self.tc.ckpt_every
+                and self.tc.ckpt_dir
+                and self.step_idx % self.tc.ckpt_every == 0
+            ):
+                with span("train.checkpoint"):
+                    self.save(
+                        os.path.join(self.tc.ckpt_dir, f"step_{self.step_idx}")
+                    )
         return self.step_idx
 
     def _flush_log(self, s: int) -> None:
         fetched = jax.device_get(self._pending)  # the ONE bulk transfer
         self._pending = []
+        records, self._records = self._records, []
         now = time.perf_counter()
         dt = now - self._t_log
         self._t_log = now
         n = len(fetched)
+        # a step's "tokens" is its loss denominator: loss positions (under
+        # MLM the masked ~15%), not real tokens; tokens_per_sec and
+        # tokens_seen count the same positions
         tokens = float(sum(m["tokens"] for m in fetched))
         self._tokens_seen += tokens
         # non-finite guard bookkeeping: the jitted step already withheld
@@ -347,20 +354,16 @@ class Trainer:
             tokens_seen=self._tokens_seen,
             skipped_total=self.skipped_total,
         )
-        if self._model_flops:
-            m["model_flops_per_sec"] = self._model_flops / step_time
-            if self.hlo_cost and self.hlo_cost.get("flops"):
-                ndev = self.mesh.size if self.mesh is not None else 1
-                m["useful_flop_ratio"] = (
-                    self._model_flops / ndev
-                ) / self.hlo_cost["flops"]
-            if self.peak_flops:
-                m["mfu"] = self._model_flops / step_time / self.peak_flops
         if self.metrics is not None:
             # registry feed: everything below is already host-side (the
-            # single bulk fetch above) — zero extra device traffic
+            # single bulk fetch above, the step log's records) — zero
+            # extra device traffic
             self._c_steps.inc(n)
             self._c_tokens.inc(tokens)
+            self._c_wait.inc(sum(r.spans.get("train.data", 0.0)
+                                 for r in records))
+            self._c_compiles.inc(sum(r.counters.get("compiles", 0)
+                                     for r in records))
             self._h_step.observe(step_time)
             for name in self._tg:
                 if name in m:

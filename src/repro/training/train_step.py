@@ -175,22 +175,25 @@ def make_train_step(model: Model, tc: TrainConfig):
             metrics = dict(
                 acc, loss=acc["loss"] / d_acc, ce_loss=acc["ce_loss"] / d_acc
             )
-        grads, gnorm = adamw.clip_by_global_norm(grads, tc.grad_clip)
-        lr = lr_at(tc, state.opt.step + 1)  # first update uses step 1 (warmup>0)
-        new_params, new_opt = adamw.apply_updates(
-            params, grads, state.opt, lr, tc
-        )
-        # non-finite guard: a diverged/poisoned step (NaN/inf loss or
-        # grad norm — the clip already rescaled by gnorm, so one bad
-        # grad taints EVERY param) applies NO update.  Params and AdamW
-        # moments keep their old values and opt.step does not advance,
-        # so the lr schedule is unaffected; the host-side Trainer counts
-        # consecutive skips and aborts past TrainConfig.max_nonfinite_skips.
-        ok = jnp.isfinite(metrics["loss"]) & jnp.isfinite(gnorm)
-        sel = lambda new, old: jax.tree.map(
-            lambda n, o: jnp.where(ok, n, o), new, old
-        )
-        params, opt = sel(new_params, params), sel(new_opt, state.opt)
+        with jax.named_scope("optimizer"):
+            grads, gnorm = adamw.clip_by_global_norm(grads, tc.grad_clip)
+            # first update uses step 1 (warmup>0)
+            lr = lr_at(tc, state.opt.step + 1)
+            new_params, new_opt = adamw.apply_updates(
+                params, grads, state.opt, lr, tc
+            )
+            # non-finite guard: a diverged/poisoned step (NaN/inf loss or
+            # grad norm — the clip already rescaled by gnorm, so one bad
+            # grad taints EVERY param) applies NO update.  Params and
+            # AdamW moments keep their old values and opt.step does not
+            # advance, so the lr schedule is unaffected; the host-side
+            # Trainer counts consecutive skips and aborts past
+            # TrainConfig.max_nonfinite_skips.
+            ok = jnp.isfinite(metrics["loss"]) & jnp.isfinite(gnorm)
+            sel = lambda new, old: jax.tree.map(
+                lambda n, o: jnp.where(ok, n, o), new, old
+            )
+            params, opt = sel(new_params, params), sel(new_opt, state.opt)
         metrics.update(
             grad_norm=gnorm, lr=lr,
             skipped=(~ok).astype(jnp.float32),

@@ -12,7 +12,7 @@ must honour:
     agree exactly across seeded chaos plans: both views are fed through
     the same ``_bump``, so they can never drift.
   * **Zero added transfers** — with the full instrumentation stack ON
-    (registry + tracer + profile timers) the engine still performs
+    (registry + tracer + step-log spans) the engine still performs
     exactly ONE bulk device->host transfer per steady-state step and the
     trainer ONE per log interval, under ``jax.transfer_guard``.
 """
@@ -29,10 +29,13 @@ from repro.models.model import build_model
 from repro.obs import (
     EVENTS,
     LATENCY_BUCKETS,
+    SCOPES,
+    STEP_LOG,
     MetricsRegistry,
-    StepTimer,
+    StepLog,
     TraceRecorder,
-    annotate,
+    scope_map,
+    span,
     trace_ctx,
 )
 from repro.serving.engine import Engine, Request
@@ -296,7 +299,7 @@ def test_chaos_counter_parity_with_health(seed):
 
 # -------------------------------------------------- transfer-guard parity
 def test_instrumented_engine_still_one_bulk_transfer_per_step(monkeypatch):
-    """The full stack ON (registry + tracer + profile timers + on_step
+    """The full stack ON (registry + tracer + step-log spans + on_step
     health probe) must not add a single device sync to the steady-state
     decode step."""
     model, params = build()
@@ -304,7 +307,7 @@ def test_instrumented_engine_still_one_bulk_transfer_per_step(monkeypatch):
     tracer = TraceRecorder()
     probes = []
     eng = Engine(model, params, slots=2, max_len=64, cache_layout="paged",
-                 page_size=8, metrics=reg, trace=tracer, profile=True,
+                 page_size=8, metrics=reg, trace=tracer,
                  on_step=lambda e: probes.append(e.health().counters))
     rng = np.random.default_rng(9)
     for i in range(2):   # fill every slot; queue empty => no admissions
@@ -320,7 +323,11 @@ def test_instrumented_engine_still_one_bulk_transfer_per_step(monkeypatch):
         n = eng.step()
     assert n == 2
     assert len(calls) == 1, f"expected 1 bulk transfer, saw {len(calls)}"
-    assert probes and eng.step_timer.totals["decode"][0] == eng.steps
+    recs = STEP_LOG.last("engine", eng.steps)
+    assert probes and [r.index for r in recs] == list(range(1, eng.steps + 1))
+    assert all(r.spans["engine.decode"] > 0 and "engine.host_sync" in r.spans
+               for r in recs)
+    assert "engine.prefill" in recs[0].spans
 
 
 def _tiny_trainer(tmp_path, reg):
@@ -334,8 +341,7 @@ def _tiny_trainer(tmp_path, reg):
     )
     ds, _ = build_synthetic_protein_memmap(str(tmp_path / "prot"), n=200,
                                            seed=0)
-    tr = Trainer(build_model(cfg), tc, verbose=False, metrics=reg,
-                 profile=True)
+    tr = Trainer(build_model(cfg), tc, verbose=False, metrics=reg)
     tr.prepare(CLMBatches(ds, 8, 32, seed=0))
     return tr, tc
 
@@ -361,7 +367,18 @@ def test_instrumented_trainer_still_one_transfer_per_interval(
     assert reg.get("train_step_time_seconds").count == 4
     assert reg.get("train_tokens_total").value == 9 * 8 * 31
     assert reg.get("train_loss").value > 0
-    assert tr.step_timer.totals["train_step"][0] == 9
+    recs = STEP_LOG.last("train", 9)
+    assert [r.index for r in recs] == list(range(9))
+    assert all(r.spans["train.dispatch"] > 0 and "train.data" in r.spans
+               for r in recs)
+    assert [r.index for r in recs if "train.flush" in r.spans] == [0, 3, 6, 8]
+    assert [r.counters.get("compiles", 0) for r in recs] == [1] + [0] * 8
+    assert "train.compile" in recs[0].spans
+    assert len({r.program for r in recs}) == 1
+    # the registry's data wait and compiles come from those records
+    assert reg.get("train_compiles_total").value == 1
+    assert reg.get("train_data_wait_seconds_total").value == pytest.approx(
+        sum(r.spans["train.data"] for r in recs))
 
 
 # ------------------------------------------------- Completion timing facts
@@ -393,19 +410,24 @@ def test_completion_ttft_none_on_queued_timeout():
 
 # ----------------------------------------------------------- profiling
 def test_step_timer_accumulates_per_phase():
-    t = [0.0]
-    timer = StepTimer(clock=lambda: t[0])
-    for dt in (1.0, 3.0):
-        with timer.span("decode"):
-            t[0] += dt
-    with timer.span("host_sync"):
-        t[0] += 0.5
-    assert timer.totals["decode"] == [2, 4.0]
-    assert timer.mean("decode") == 2.0
-    assert timer.mean("missing") == 0.0
-    s = timer.summary()
+    """The step log's spans accumulate per phase over steps (what the
+    step timer did), and the summary averages over the steps that ran
+    each span."""
+    clock = FakeClock(0.0)
+    log = StepLog(clock=clock)
+    for i, dt in enumerate((1.0, 3.0)):
+        with log.step("engine", i):
+            with log.span("decode"):
+                clock.advance(dt)
+    with log.step("engine", 2):
+        with log.span("host_sync"):
+            clock.advance(0.5)
+    s = log.summary()
+    assert s["decode"] == {"count": 2, "total_s": 4.0, "mean_s": 2.0}
     assert s["host_sync"]["count"] == 1
-    assert "decode: n=2 mean=2000.000ms" in timer.report()
+    assert "missing" not in s
+    assert "decode: n=2 mean=2000.000ms" in log.report()
+    assert log.summary("train") == {}
 
 
 def test_profile_hooks_are_noops_when_disabled(tmp_path):
@@ -413,14 +435,116 @@ def test_profile_hooks_are_noops_when_disabled(tmp_path):
         pass
     with trace_ctx(None):
         pass
-    with annotate("x", enabled=False):
+    # a span outside any step only annotates: it must survive on a
+    # CPU-only wheel and record nothing
+    n = len(STEP_LOG.records())
+    with span("engine.decode"):
         pass
-    # enabled path must also survive on a CPU-only wheel (real annotation
-    # or graceful no-op, never a raise)
-    with annotate("engine/decode", enabled=True):
-        pass
+    assert len(STEP_LOG.records()) == n
     with trace_ctx(str(tmp_path / "prof")):
-        jax.block_until_ready(jax.numpy.ones(4) * 2)
+        with STEP_LOG.step("test", 0), span("test.work"):
+            jax.block_until_ready(jax.numpy.ones(4) * 2)
+
+
+def test_step_log_records_nesting_and_counters():
+    """Fake clock: a record holds its wall start and end, each span's
+    seconds (a nested span counted in both), repeated spans summed, and
+    counters; spans and counters go to the innermost open step."""
+    clock = FakeClock(10.0)
+    log = StepLog(clock=clock)
+    with log.step("train", 7) as rec:
+        assert log.last("train", 1) == []       # still open
+        with log.span("train.flush"):
+            clock.advance(1.0)
+            with log.span("train.inner"):
+                clock.advance(0.25)
+        with log.span("train.flush"):
+            clock.advance(0.5)
+        log.count("compiles")
+        log.count("compiles", 2)
+        with log.step("engine", 0) as inner:
+            log.count("x")
+            with log.span("engine.decode"):
+                clock.advance(2.0)
+        clock.advance(0.125)
+    assert (rec.kind, rec.index, rec.start, rec.end) == ("train", 7, 10.0, 13.875)
+    assert rec.spans == {"train.flush": 1.75, "train.inner": 0.25}
+    assert rec.counters == {"compiles": 3}
+    assert inner.counters == {"x": 1} and inner.spans == {"engine.decode": 2.0}
+    assert log.last("train", 5) == [rec]
+    assert log.records() == [rec, inner]
+    log.count("outside")                        # no open step: dropped
+    assert rec.counters == {"compiles": 3}
+
+
+def test_step_log_ring_is_bounded():
+    log = StepLog(capacity=3, programs=2, clock=FakeClock())
+    for i in range(5):
+        with log.step("train", i):
+            pass
+    assert [r.index for r in log.records()] == [2, 3, 4]
+    assert [r.index for r in log.last("train", 2)] == [3, 4]
+    assert [r.index for r in log.last("train", 9)] == [2, 3, 4]
+    assert log.last("train", 0) == []
+    for k in "abc":
+        log.add_program(k, lambda k=k: {"i": (k, "fwd")})
+    assert log.scopes("a") == {}                # beyond the bound
+    assert log.scopes("c") == {"i": ("c", "fwd")}
+
+
+def test_scope_map_is_built_lazily_on_a_tiny_model(tmp_path, monkeypatch):
+    """The trainer registers each compiled step program; its scope map is
+    read from the compiled HLO only at the first read, names every layer
+    scope, and tells the passes apart (the tiny model's blocks are
+    rematerialised under the default ``remat_policy="block"``)."""
+    import repro.training.loop as loop
+
+    texts = []
+    monkeypatch.setattr(loop, "scope_map",
+                        lambda t: texts.append(1) or scope_map(t))
+    tr, _ = _tiny_trainer(tmp_path, None)
+    tr.step()
+    tr.step()
+    key = STEP_LOG.last("train", 1)[0].program
+    assert texts == []                          # nothing read yet
+    m = STEP_LOG.scopes(key)
+    assert STEP_LOG.scopes(key) is m and texts == [1]  # built once
+    got = set(m.values())
+    for scope in SCOPES:
+        assert any(s == scope for s, _ in got), scope
+    for pair in [("attention", "fwd"), ("attention", "bwd"),
+                 ("attention", "remat"), ("ffn", "remat"), ("head", "fwd"),
+                 ("head", "bwd"), ("optimizer", "step")]:
+        assert pair in got, pair
+    # the join with a trace: an instruction printed as the profiler does
+    name = next(k for k, v in m.items() if v == ("optimizer", "step"))
+    recs = STEP_LOG.last("train", 2)
+    by = STEP_LOG.device_seconds(
+        {f"%{name} = f32[4]{{0}} fusion(f32[4]{{0}} %p)": 1.5,
+         "%not.an.instruction = f32[] add()": 0.5}, recs)
+    assert by == {("optimizer", "step"): 1.5, (None, None): 0.5}
+    assert StepLog().device_seconds({"x": 1.0}, recs) == {}
+
+
+def test_scope_map_classifies_op_names():
+    text = "\n".join([
+        'ENTRY %main {',
+        '  %a.1 = f32[] add(), metadata={op_name="jit(f)/jvp()/while/body/'
+        'closed_call/attention/dot_general" stack_frame_id=1}',
+        '  %b.2 = f32[] mul(), metadata={op_name="jit(f)/transpose(jvp())/'
+        'while/body/closed_call/checkpoint/rematted_computation/ffn/mul"}',
+        '  ROOT %c.3 = f32[] mul(), metadata={op_name="jit(f)/'
+        'transpose(jvp(head))/cross_entropy_dw/pallas_call"}',
+        '  %d.4 = f32[] sub(), metadata={op_name="jit(f)/optimizer/sub"}',
+        '  %e.5 = f32[] sub(), metadata={}',
+        '  %f.6 = f32[] sub(), metadata={op_name="jit(f)/jvp()/convert"}',
+        '}',
+    ])
+    assert scope_map(text) == {
+        "a.1": ("attention", "fwd"), "b.2": ("ffn", "remat"),
+        "c.3": ("head", "bwd"), "d.4": ("optimizer", "step"),
+        "f.6": (None, "fwd"),
+    }
 
 
 def test_trace_ctx_raises_when_trace_cannot_start(tmp_path):

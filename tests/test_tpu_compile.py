@@ -118,6 +118,39 @@ def test_cross_entropy_fwd_bwd(chip, arch, precision):
         )
 
 
+def test_training_kernels_carry_their_names(chip):
+    """The training path's kernels pass ``name=`` to ``pallas_call``: the
+    compiled HLO names each custom call after its kernel, and the trace
+    of a step shows those names (``repro.obs.scope_map`` reads them)."""
+    import re
+
+    def loss(q, k, v, h, w, t):
+        o = ops.attention(q, k, v, causal=False, impl="pallas")
+        ce, _ = ops.cross_entropy(h, w, t, vocab=33, impl="pallas")
+        return jnp.sum(o.astype(F32)) + jnp.sum(ce)
+
+    text = _compile(
+        chip, jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
+        ((2, 256, 4, 64), BF16), ((2, 256, 4, 64), BF16),
+        ((2, 256, 4, 64), BF16), ((512, 1280), BF16), ((1280, 256), BF16),
+        ((512,), I32),
+    )
+    calls = [line.split(" = ")[0].strip() for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    kernels = ["flash_attention_fwd", "flash_attention_dq",
+               "flash_attention_dkv", "cross_entropy_fwd", "cross_entropy_dh",
+               "cross_entropy_dw"]
+    assert len(calls) == len(kernels), calls
+    for k in kernels:
+        assert any(re.fullmatch(rf"(ROOT )?%(\w+_)?{k}_*\.\d+", c)
+                   for c in calls), (k, calls)
+    norm = _compile(
+        chip, lambda x, w, b: layernorm(x, w, b),
+        ((8192, 1280), BF16), ((1280,), F32), ((1280,), F32),
+    )
+    assert re.search(r"%layernorm(\.\d+)? = ", norm)
+
+
 def test_flash_decode(chip):
     # qwen2-7b decode: 8 slots over a 4096-token dense cache
     _compile(
