@@ -136,6 +136,39 @@ def _blockwise_attention_xla(
     return jnp.transpose(out, (0, 2, 1, 3)).astype(q.dtype)
 
 
+# (block_q, block_k) of a bidirectional, unwindowed Pallas attention call
+# (the ESM-2/BERT encoders): large tiles amortise each grid step's fixed
+# cost; see kernels/README.md "Tiles".
+BIDIRECTIONAL_BLOCKS = (1024, 1024)
+
+
+def _bidirectional_block(n: int, block: int) -> int:
+    """The largest of block, block/2, ... 128 that is at most ``n`` and
+    tiles ``n`` rounded up to 128, so every tile is 128-aligned and no
+    length pads further than 128-row tiles would.  A length of at most
+    128, or a multiple of 128 up to ``block``, is one tile."""
+    padded = n + (-n % 128)
+    if n <= 128 or (n == padded and n <= block):
+        return n
+    while padded % block or block > n:
+        block //= 2
+    return block
+
+
+def attention_blocks(
+    S: int, T: int, *, causal: bool, window: int
+) -> Tuple[int, int]:
+    """(block_q, block_k) of the Pallas kernels for queries of length S
+    over T keys.  Causal and windowed calls (decoder prefill and training)
+    keep 128 × 128, the granularity at which their kernels skip dead
+    tiles; bidirectional calls take ``BIDIRECTIONAL_BLOCKS``.  A tile is
+    capped at its length, as ``tiling.pick_block`` does."""
+    if causal or window > 0:
+        return tiling.pick_block(S, 128)[0], tiling.pick_block(T, 128)[0]
+    bq, bk = BIDIRECTIONAL_BLOCKS
+    return _bidirectional_block(S, bq), _bidirectional_block(T, bk)
+
+
 class _AttnCfg(NamedTuple):
     """Hashable static config for the pallas attention custom-VJP."""
 
@@ -194,9 +227,12 @@ def attention(
 ) -> jax.Array:
     impl, interpret = _resolve(impl, interpret)
     if impl == "pallas":
+        block_q, block_k = attention_blocks(
+            q.shape[1], k.shape[1], causal=causal, window=window
+        )
         cfg = _AttnCfg(
             causal=causal, window=window, softcap=softcap, q_offset=q_offset,
-            block_q=128, block_k=128, interpret=interpret,
+            block_q=block_q, block_k=block_k, interpret=interpret,
         )
         return _attention_pallas(cfg, q, k, v)
     if impl == "naive":

@@ -90,6 +90,31 @@ def test_attention_vjp_bf16(impl):
         )
 
 
+def test_attention_vjp_large_tiles_bf16():
+    """The trainable path at a bidirectional bf16 shape on which the tile
+    rule picks tiles above 128 (512 x 512, so the forward builds no
+    per-element mask). Forward and all three cotangents against the fp32
+    oracle and its jax.grad."""
+    B, S, H, D = 1, 512, 2, 64
+    assert min(ops.attention_blocks(S, S, causal=False, window=0)) > 128
+    q, k, v, do = _attn_inputs(B, S, S, H, H, D)
+    q, k, v = (x.astype(jnp.bfloat16) for x in (q, k, v))
+
+    def f(which):
+        def loss(q, k, v):
+            out = ops.attention(q, k, v, causal=False, impl=which, interpret=True)
+            return (out.astype(jnp.float32) * do).sum(), out
+        return loss
+
+    (_, out), got = jax.value_and_grad(f("pallas"), argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    (_, o_ref), want = jax.value_and_grad(f("naive"), argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    for name, g, w in zip(("out", "dq", "dk", "dv"), (out,) + got, (o_ref,) + want):
+        assert g.dtype == jnp.bfloat16, name
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        np.testing.assert_allclose(g, w, atol=5e-2, rtol=5e-2, err_msg=name)
+        assert np.linalg.norm(g - w) / np.linalg.norm(w) < 1e-2, name
+
+
 def _ce_inputs(T, D, V, Vp):
     ks = jax.random.split(KEY, 4)
     h = jax.random.normal(ks[0], (T, D))

@@ -4,7 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ref
+from repro.kernels import ops, ref
 from repro.kernels.cross_entropy import fused_cross_entropy
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.rmsnorm import layernorm, rmsnorm
@@ -43,6 +43,52 @@ def test_flash_attention_sweep(B, S, T, H, Hkv, D, dtype, causal, window, softca
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(want, np.float32), **tol(dtype)
     )
+
+
+@pytest.mark.parametrize("S,T,causal,window,want", [
+    # the ESM-2 cell's shape: bidirectional, 1024 x 1024
+    (1024, 1024, False, 0, ops.BIDIRECTIONAL_BLOCKS),
+    # causal and windowed calls keep 128 x 128
+    (1024, 1024, True, 0, (128, 128)),
+    (4096, 4096, True, 0, (128, 128)),
+    (1024, 1024, False, 256, (128, 128)),
+    (1024, 1024, True, 512, (128, 128)),
+    # lengths under the tile are capped, as pick_block does
+    (64, 64, True, 0, (64, 64)),
+    (37, 53, True, 0, (37, 53)),
+    (1, 300, True, 0, (1, 128)),
+    (64, 192, False, 0, (64, 128)),
+    (200, 200, False, 0, (128, 128)),
+    # a multiple of 128 up to the tile is one tile
+    (384, 384, False, 0, (384, 384)),
+    # otherwise never padded further than 128-row tiles, and 128-aligned
+    (1000, 1000, False, 0, (512, 512)),
+    (2048, 2048, False, 0, ops.BIDIRECTIONAL_BLOCKS),
+    (1536, 1536, False, 0, (512, 512)),
+    (1500, 2000, False, 0, (512, 1024)),
+])
+def test_attention_blocks_rule(S, T, causal, window, want):
+    got = ops.attention_blocks(S, T, causal=causal, window=window)
+    assert got == tuple(want)
+    for n, b in zip((S, T), got):
+        assert b <= n
+        assert b % 128 == 0 or b == n <= 128
+        assert -n % b <= -n % 128 or n <= 128
+
+
+def test_flash_attention_large_tiles_bf16():
+    """A bidirectional bf16 call on which the tile rule picks tiles above
+    128 (here one 512 x 512 tile, so the forward builds no per-element
+    mask), against the fp32 oracle."""
+    B, S, H, D = 1, 512, 2, 64
+    assert min(ops.attention_blocks(S, S, causal=False, window=0)) > 128
+    ks = jax.random.split(KEY, 3)
+    q, k, v = (jax.random.normal(kk, (B, S, H, D), jnp.bfloat16) for kk in ks)
+    out = ops.attention(q, k, v, causal=False, impl="pallas_interpret")
+    want = ref.attention_ref(q, k, v, causal=False)
+    got, want = np.asarray(out, np.float32), np.asarray(want, np.float32)
+    np.testing.assert_allclose(got, want, **tol(jnp.bfloat16))
+    assert np.linalg.norm(got - want) / np.linalg.norm(want) < 1e-2
 
 
 @pytest.mark.parametrize("rows,d", [(32, 128), (64, 256), (128, 512), (8, 1024)])

@@ -72,10 +72,14 @@ def _compile(chip, fn, *shapes):
     return text
 
 
-# (B, S, H, Hkv, D, causal): the ESM-2 650M train step and a qwen2-7b
-# prefill of a 512-token prompt
+# (B, S, H, Hkv, D, causal): the ESM-2 650M train step, at 8 rows and at
+# the benchmark cell's 16 (the tiles it runs), the whisper-medium encoder
+# over 1,500 frames (512-row tiles over keys padded to 1,536, so masked)
+# and a qwen2-7b prefill of a 512-token prompt
 ATTN = {
     "esm2-650m": (8, 1024, 20, 20, 64, False),
+    "esm2-650m.mlm": (16, 1024, 20, 20, 64, False),
+    "whisper-medium.encoder": (1, 1500, 16, 16, 64, False),
     "qwen2-7b": (1, 512, 28, 4, 128, True),
 }
 
@@ -92,6 +96,33 @@ def test_flash_attention_fwd_bwd(chip, arch):
         chip, jax.grad(loss, argnums=(0, 1, 2)),
         ((B, S, H, D), BF16), ((B, S, Hkv, D), BF16), ((B, S, Hkv, D), BF16),
     )
+
+
+def test_flash_attention_signatures(chip):
+    """At the cell's shape the compiled step holds one forward kernel whose
+    result is a head-major bf16 (B*H, S, D) block and the fp32 (B*H, S, 128)
+    per-row statistics, one dQ kernel returning one such block and one
+    dK/dV kernel returning two: the signatures by which the benchmark's
+    roofline reader tells the kernels apart."""
+    B, S, H, _, D, _ = ATTN["esm2-650m.mlm"]
+
+    def loss(q, k, v):
+        o = ops.attention(q, k, v, causal=False, impl="pallas")
+        return jnp.sum(o.astype(F32))
+
+    text = _compile(
+        chip, jax.grad(loss, argnums=(0, 1, 2)),
+        ((B, S, H, D), BF16), ((B, S, H, D), BF16), ((B, S, H, D), BF16),
+    )
+    block, rows = f"bf16[{B * H},{S},{D}]", f"f32[{B * H},{S},128]"
+    results = [line.split(" = ", 1)[1].split(" custom-call(")[0]
+               for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(results) == 3, results
+    fwd = [r for r in results if r.count(block) == 1 and rows in r]
+    dq = [r for r in results if r.count(block) == 1 and r.startswith("bf16[")]
+    dkv = [r for r in results if r.count(block) == 2]
+    assert len(fwd) == len(dq) == len(dkv) == 1, results
 
 
 # (tokens, d_model, padded vocab, vocab)
