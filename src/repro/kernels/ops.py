@@ -29,6 +29,7 @@ from repro.kernels import ref
 from repro.kernels import tiling
 from repro.kernels import flash_attention as _fa
 from repro.kernels import cross_entropy as _ce
+from repro.kernels import rope as _rope
 from repro.kernels.rmsnorm import layernorm as _ln_pallas
 from repro.kernels.rmsnorm import rmsnorm as _rms_pallas
 from repro.kernels.ssd_scan import ssd_scan as _ssd_pallas
@@ -183,12 +184,11 @@ class _AttnCfg(NamedTuple):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _attention_pallas(cfg: _AttnCfg, q, k, v):
-    out, _ = _fa.flash_attention_fwd(
+    return _fa.flash_attention(
         q, k, v, causal=cfg.causal, window=cfg.window, softcap=cfg.softcap,
         q_offset=cfg.q_offset, block_q=cfg.block_q, block_k=cfg.block_k,
         interpret=cfg.interpret,
     )
-    return out
 
 
 def _attention_pallas_fwd(cfg: _AttnCfg, q, k, v):
@@ -242,6 +242,49 @@ def attention(
     return _blockwise_attention_xla(
         q, k, v, causal=causal, window=window, softcap=softcap, q_offset=q_offset
     )
+
+
+# --------------------------------------------------------------------- #
+# rotary position embedding
+# --------------------------------------------------------------------- #
+def _rope_xla(x, positions, theta):
+    half = x.shape[-1] // 2
+    ang = _rope.angles(positions, theta, x.shape[-1])[..., None, :]  # (…, S, 1, half)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x1, x2 = x[..., :half], x[..., half:]
+    xf1, xf2 = x1.astype(jnp.float32), x2.astype(jnp.float32)
+    out = jnp.concatenate([xf1 * cos - xf2 * sin, xf2 * cos + xf1 * sin], axis=-1)
+    return out.astype(x.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _rope_pallas(x, positions, theta, interpret):
+    return _rope.rope(x, positions, theta, interpret=interpret)
+
+
+def _rope_pallas_fwd(x, positions, theta, interpret):
+    return _rope_pallas(x, positions, theta, interpret), positions
+
+
+def _rope_pallas_bwd(theta, interpret, positions, dy):
+    # a rotation's transpose is the rotation by the opposite angle
+    return _rope.rope(dy, positions, theta, sign=-1.0, interpret=interpret), None
+
+
+_rope_pallas.defvjp(_rope_pallas_fwd, _rope_pallas_bwd)
+
+
+def rope(x: jax.Array, positions: jax.Array, theta: float, *,
+         impl: str = "auto", interpret: bool = False) -> jax.Array:
+    """Rotate-half rotary embedding of x (B, S, H, D) at ``positions``
+    (S,) or (B, S).  ``pallas`` works on the (B, S, H·D) rows the
+    projections write and the attention kernels read (``rope.py``) where
+    whole heads fill 128-lane blocks; elsewhere the XLA formula runs."""
+    impl, interpret = _resolve(impl, interpret)
+    H, D = x.shape[2:]
+    if impl == "pallas" and _rope.LANES % D == 0 and H * D % _rope.LANES == 0:
+        return _rope_pallas(x, positions, theta, interpret)
+    return _rope_xla(x, positions, theta)
 
 
 def decode_attention(

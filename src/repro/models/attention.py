@@ -12,11 +12,11 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec
 
 from repro.core.config import ModelConfig
 from repro.core.module import P
 from repro.kernels import ops
-from repro.models.layers import rope
 from repro.obs.profile import scoped
 from repro.parallel.sharding import ShardingCtx
 
@@ -115,8 +115,21 @@ def attention_apply(
                     positions = cache_pos[:, None].astype(jnp.int32)  # (B,1)
             else:
                 positions = jnp.arange(S)
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
+        # the RoPE kernel where q and k go on to the flash kernels, which
+        # read the (B, S, H·D) rows it writes; decode and chunked prefill
+        # feed the paged kernels and keep the XLA formula.  On a mesh the
+        # kernel runs per shard: batch rows, heads over `model` where they
+        # divide it
+        impl = cfg.kernel_impl if mode in ("train", "prefill") else "xla"
+        rot = functools.partial(ops.rope, theta=cfg.rope_theta, impl=impl)
+        ps = ctx.fit(positions.shape, "batch") if positions.ndim == 2 else PartitionSpec()
+
+        def rotate(x):
+            xs = ctx.fit(x.shape, "batch", None, "tp", None)
+            return ctx.per_shard(rot, (xs, ps), xs,
+                                 when=ops.is_pallas(impl))(x, positions)
+
+        q, k = rotate(q), rotate(k)
 
     if mode == "chunk":
         # chunked / suffix prefill over the paged layout (serving engine):

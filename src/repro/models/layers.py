@@ -1,4 +1,4 @@
-"""Shared layers: norms, rotary embeddings, MLPs, embeddings.
+"""Shared layers: norms, MLPs, embeddings (rotary embeddings: ``ops.rope``).
 
 Every layer is a pair (``*_defs`` returning a P-tree, ``*_apply`` pure fn).
 """
@@ -40,27 +40,6 @@ def norm_apply(
         when=ops.is_pallas("auto"),
     )
     return norm(x, *w)
-
-
-# --------------------------------------------------------------------- #
-# rotary position embedding
-# --------------------------------------------------------------------- #
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (B, S, H, D); positions: (S,) or (B, S)."""
-    D = x.shape[-1]
-    half = D // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
-    if positions.ndim == 1:
-        ang = positions.astype(jnp.float32)[None, :, None] * freqs[None, None, :]
-        ang = ang[:, :, None, :]                       # (1, S, 1, half)
-    else:
-        ang = positions.astype(jnp.float32)[:, :, None] * freqs[None, None, :]
-        ang = ang[:, :, None, :]                       # (B, S, 1, half)
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    x1, x2 = x[..., :half], x[..., half:]
-    xf1, xf2 = x1.astype(jnp.float32), x2.astype(jnp.float32)
-    out = jnp.concatenate([xf1 * cos - xf2 * sin, xf2 * cos + xf1 * sin], axis=-1)
-    return out.astype(x.dtype)
 
 
 # --------------------------------------------------------------------- #

@@ -12,6 +12,8 @@ import pytest
 
 from repro.kernels import ops, ref
 
+from attention_cases import TILED
+
 KEY = jax.random.PRNGKey(11)
 
 GRAD_TOL = dict(atol=2e-4, rtol=2e-3)
@@ -35,13 +37,17 @@ ATTN_VARIANTS = [
     (False, 24, 15.0),
 ]
 # MHA, GQA, MQA; square and offset (T > S, decode-style); odd lengths;
-# prime lengths exercise the pallas pad+mask tiling path
+# prime lengths exercise the pallas pad+mask tiling path.  D = 64 with an
+# even head count and D = 128 take the lane-dense kernels, the rest the
+# head-major ones.
 ATTN_SHAPES = [
     (2, 64, 64, 4, 4, 32),
     (1, 64, 64, 4, 2, 16),
     (1, 48, 80, 4, 1, 16),
     (1, 40, 40, 2, 2, 16),
     (1, 37, 53, 2, 1, 16),
+    (1, 37, 53, 4, 4, 64),
+    (1, 48, 80, 4, 2, 128),
 ]
 
 
@@ -90,19 +96,19 @@ def test_attention_vjp_bf16(impl):
         )
 
 
-def test_attention_vjp_large_tiles_bf16():
-    """The trainable path at a bidirectional bf16 shape on which the tile
-    rule picks tiles above 128 (512 x 512, so the forward builds no
-    per-element mask). Forward and all three cotangents against the fp32
-    oracle and its jax.grad."""
-    B, S, H, D = 1, 512, 2, 64
-    assert min(ops.attention_blocks(S, S, causal=False, window=0)) > 128
-    q, k, v, do = _attn_inputs(B, S, S, H, H, D)
+@pytest.mark.parametrize("B,S,T,H,Hkv,D,causal,window", TILED)
+def test_attention_vjp_large_tiles_bf16(B, S, T, H, Hkv, D, causal, window):
+    """The trainable path in bf16 at the tiles the tile rule picks (the
+    ESM-2 cell's 1024², a mask-free 512², unaligned lengths, GQA, cross-
+    attention, and one head-major shape). Forward and all three
+    cotangents against the fp32 oracle and its jax.grad."""
+    q, k, v, do = _attn_inputs(B, S, T, H, Hkv, D)
     q, k, v = (x.astype(jnp.bfloat16) for x in (q, k, v))
 
     def f(which):
         def loss(q, k, v):
-            out = ops.attention(q, k, v, causal=False, impl=which, interpret=True)
+            out = ops.attention(q, k, v, causal=causal, window=window,
+                                q_offset=T - S, impl=which, interpret=True)
             return (out.astype(jnp.float32) * do).sum(), out
         return loss
 

@@ -4,11 +4,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels import flash_attention as fa
 from repro.kernels import ops, ref
 from repro.kernels.cross_entropy import fused_cross_entropy
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.rmsnorm import layernorm, rmsnorm
 from repro.kernels.ssd_scan import ssd_scan
+
+from attention_cases import TILED
 
 KEY = jax.random.PRNGKey(42)
 
@@ -18,10 +21,12 @@ def tol(dtype):
 
 
 @pytest.mark.parametrize("B,S,T,H,Hkv,D", [
-    (2, 128, 128, 4, 2, 64),
-    (1, 256, 256, 8, 8, 128),
+    (2, 128, 128, 4, 2, 64),      # head-major: GQA at D = 64
+    (1, 256, 256, 8, 8, 128),     # lane-dense, a head a block
     (2, 64, 192, 6, 1, 64),
     (1, 128, 128, 4, 4, 128),
+    (2, 128, 128, 4, 4, 64),      # lane-dense, a head pair a block
+    (1, 64, 192, 6, 6, 64),
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("causal,window,softcap", [
@@ -33,7 +38,8 @@ def test_flash_attention_sweep(B, S, T, H, Hkv, D, dtype, causal, window, softca
     k = jax.random.normal(ks[1], (B, T, Hkv, D), dtype)
     v = jax.random.normal(ks[2], (B, T, Hkv, D), dtype)
     off = T - S
-    out = flash_attention(
+    # the forward kernel of the trainable path, whose layout goes by shape
+    out, _ = fa.flash_attention_fwd(
         q, k, v, causal=causal, window=window, softcap=softcap,
         q_offset=off, block_q=64, block_k=64, interpret=True,
     )
@@ -76,19 +82,55 @@ def test_attention_blocks_rule(S, T, causal, window, want):
         assert -n % b <= -n % 128 or n <= 128
 
 
-def test_flash_attention_large_tiles_bf16():
-    """A bidirectional bf16 call on which the tile rule picks tiles above
-    128 (here one 512 x 512 tile, so the forward builds no per-element
-    mask), against the fp32 oracle."""
-    B, S, H, D = 1, 512, 2, 64
-    assert min(ops.attention_blocks(S, S, causal=False, window=0)) > 128
+@pytest.mark.parametrize("B,S,T,H,Hkv,D,causal,window", TILED)
+def test_flash_attention_large_tiles_bf16(B, S, T, H, Hkv, D, causal, window):
+    """bf16 calls at the tiles the tile rule picks, against the fp32
+    oracle: the ESM-2 cell's 1024² tiles (and one 512² tile, so the
+    forward builds no per-element mask), unaligned lengths, GQA, cross-
+    attention, and one shape that takes the head-major kernels.  That
+    shape, and every causal call (a forward with no backward), return
+    exactly what the head-major kernel returns called directly."""
+    if not causal and not window and S == T and S % 128 == 0:
+        assert min(ops.attention_blocks(S, S, causal=False, window=0)) > 128
     ks = jax.random.split(KEY, 3)
-    q, k, v = (jax.random.normal(kk, (B, S, H, D), jnp.bfloat16) for kk in ks)
-    out = ops.attention(q, k, v, causal=False, impl="pallas_interpret")
-    want = ref.attention_ref(q, k, v, causal=False)
+    q = jax.random.normal(ks[0], (B, S, H, D), jnp.bfloat16)
+    k, v = (jax.random.normal(kk, (B, T, Hkv, D), jnp.bfloat16) for kk in ks[1:])
+    out = ops.attention(q, k, v, causal=causal, window=window, q_offset=T - S,
+                        impl="pallas_interpret")
+    want = ref.attention_ref(q, k, v, causal=causal, window=window, q_offset=T - S)
+    if causal or not fa.heads_per_block(H, Hkv, D):
+        bq, bk = ops.attention_blocks(S, T, causal=causal, window=window)
+        direct, _ = fa._fwd_head_major(
+            q, k, v, causal=causal, window=window, softcap=0.0,
+            q_offset=T - S, block_q=bq, block_k=bk, interpret=True)
+        assert jnp.array_equal(out, direct)
     got, want = np.asarray(out, np.float32), np.asarray(want, np.float32)
     np.testing.assert_allclose(got, want, **tol(jnp.bfloat16))
     assert np.linalg.norm(got - want) / np.linalg.norm(want) < 1e-2
+
+
+@pytest.mark.parametrize("B,S,H,D,pos", [
+    (2, 37, 4, 64, "seq"),        # a head pair a 128-lane tile
+    (2, 16, 4, 128, "rows"),      # per-row positions (B, S)
+    (1, 8, 8, 16, "seq"),         # eight heads a tile
+    (3, 1, 2, 128, "rows"),       # one token a row
+    (1, 3000, 20, 64, "seq"),     # 512-row blocks (256 in fp32) over 3,072
+])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_rope_sweep(B, S, H, D, pos, dtype):
+    """The Pallas RoPE kernel and its gradient (the opposite rotation)
+    against the XLA formula: equal to within an ulp of fp32."""
+    ks = jax.random.split(KEY, 3)
+    x, dy = (jax.random.normal(kk, (B, S, H, D), dtype) for kk in ks[:2])
+    positions = (jnp.arange(S) if pos == "seq"
+                 else jax.random.randint(ks[2], (B, S), 0, 4096))
+    outs = [jax.vjp(lambda x: ops.rope(x, positions, 1e4, impl=impl), x)
+            for impl in ("pallas_interpret", "xla")]
+    (got, got_vjp), (want, want_vjp) = outs
+    t = dict(atol=1e-6, rtol=1e-6) if dtype == jnp.float32 else dict(atol=1e-2, rtol=1e-2)
+    for g, w in ((got, want), (got_vjp(dy)[0], want_vjp(dy)[0])):
+        assert g.dtype == dtype
+        np.testing.assert_allclose(np.asarray(g, np.float32), np.asarray(w, np.float32), **t)
 
 
 @pytest.mark.parametrize("rows,d", [(32, 128), (64, 256), (128, 512), (8, 1024)])

@@ -9,7 +9,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro.core.config import ModelConfig, TrainConfig
 from repro.kernels import ops, ref
-from repro.models.layers import rope
+from repro.kernels.ops import rope
 from repro.optim import adamw
 from repro.optim.schedule import lr_at
 

@@ -72,21 +72,26 @@ def _compile(chip, fn, *shapes):
     return text
 
 
-# (B, S, H, Hkv, D, causal): the ESM-2 650M train step, at 8 rows and at
-# the benchmark cell's 16 (the tiles it runs), the whisper-medium encoder
-# over 1,500 frames (512-row tiles over keys padded to 1,536, so masked)
-# and a qwen2-7b prefill of a 512-token prompt
+# (B, S, T, H, Hkv, D, causal): the ESM-2 650M train step, at 8 rows and
+# at the benchmark cell's 16 (the tiles it runs), the whisper-medium
+# encoder over 1,500 frames (512-row tiles over keys padded to 1,536, so
+# masked) and its decoder's cross-attention of a 37-token prompt over
+# them, and qwen2-7b prefills of 512 tokens and of the serving engine's
+# short buckets: 8 tokens, and 37 (a tile of the whole unaligned length)
 ATTN = {
-    "esm2-650m": (8, 1024, 20, 20, 64, False),
-    "esm2-650m.mlm": (16, 1024, 20, 20, 64, False),
-    "whisper-medium.encoder": (1, 1500, 16, 16, 64, False),
-    "qwen2-7b": (1, 512, 28, 4, 128, True),
+    "esm2-650m": (8, 1024, 1024, 20, 20, 64, False),
+    "esm2-650m.mlm": (16, 1024, 1024, 20, 20, 64, False),
+    "whisper-medium.encoder": (1, 1500, 1500, 16, 16, 64, False),
+    "whisper-medium.cross37": (1, 37, 1500, 16, 16, 64, False),
+    "qwen2-7b": (1, 512, 512, 28, 4, 128, True),
+    "qwen2-7b.prefill8": (1, 8, 8, 28, 4, 128, True),
+    "qwen2-7b.prefill37": (1, 37, 37, 28, 4, 128, True),
 }
 
 
 @pytest.mark.parametrize("arch", sorted(ATTN))
 def test_flash_attention_fwd_bwd(chip, arch):
-    B, S, H, Hkv, D, causal = ATTN[arch]
+    B, S, T, H, Hkv, D, causal = ATTN[arch]
 
     def loss(q, k, v):
         o = ops.attention(q, k, v, causal=causal, impl="pallas")
@@ -94,35 +99,99 @@ def test_flash_attention_fwd_bwd(chip, arch):
 
     _compile(
         chip, jax.grad(loss, argnums=(0, 1, 2)),
-        ((B, S, H, D), BF16), ((B, S, Hkv, D), BF16), ((B, S, Hkv, D), BF16),
+        ((B, S, H, D), BF16), ((B, T, Hkv, D), BF16), ((B, T, Hkv, D), BF16),
     )
 
 
-def test_flash_attention_signatures(chip):
-    """At the cell's shape the compiled step holds one forward kernel whose
-    result is a head-major bf16 (B*H, S, D) block and the fp32 (B*H, S, 128)
-    per-row statistics, one dQ kernel returning one such block and one
-    dK/dV kernel returning two: the signatures by which the benchmark's
-    roofline reader tells the kernels apart."""
-    B, S, H, _, D, _ = ATTN["esm2-650m.mlm"]
+@pytest.mark.parametrize("arch", ["esm2-650m", "whisper-medium.cross37", "qwen2-7b",
+                                  "qwen2-7b.prefill8", "qwen2-7b.prefill37"])
+def test_flash_attention_forward_only(chip, arch):
+    """A call with no backward (a prefill) compiles with one forward
+    kernel: the head-major one for a causal call, the lane-dense one
+    otherwise (``flash_attention.flash_attention``)."""
+    import re
+
+    B, S, T, H, Hkv, D, causal = ATTN[arch]
+    text = _compile(
+        chip, lambda q, k, v: ops.attention(q, k, v, causal=causal, impl="pallas"),
+        ((B, S, H, D), BF16), ((B, T, Hkv, D), BF16), ((B, T, Hkv, D), BF16),
+    )
+    names = re.findall(r"%(?:\w+?_)?(flash_attention_\w+?)_*\.\d+ = .*tpu_custom_call", text)
+    want = "flash_attention_fwd_head_major" if causal else "flash_attention_fwd"
+    assert names == [want], names
+
+
+@pytest.mark.parametrize("arch", ["esm2-650m.mlm", "qwen2-7b"])
+def test_flash_attention_signatures(chip, arch):
+    """The boundary of the lane-dense kernels, as the model's step has it:
+    q, k, v come as the projections write them, (B, S, H·D) rows, and go
+    through RoPE first.  The compiled gradient holds one forward, one dQ
+    and one dK/dV kernel, under those names, whose bf16 operands are all
+    (B, S, H·D) or (B, S, Hkv·D), and nothing around them relayouts q, k,
+    v, o or their cotangents: no copy or transpose of a head-major
+    (B, H, S, D) or (B·H, S, D) array, of (B, S, H, D), or of any array
+    of q's shape, and no fp32 (…, 128) broadcast of per-row statistics.
+    At the ESM-2 cell's shape a block holds a head pair; qwen2-7b's GQA
+    call takes a head a block."""
+    import re
+
+    B, S, _, H, Hkv, D, causal = ATTN[arch]
 
     def loss(q, k, v):
-        o = ops.attention(q, k, v, causal=False, impl="pallas")
+        q, k = (ops.rope(x.reshape(B, S, h, D), jnp.arange(S), 1e4, impl="pallas")
+                for x, h in ((q, H), (k, Hkv)))
+        o = ops.attention(q, k, v.reshape(B, S, Hkv, D), causal=causal, impl="pallas")
         return jnp.sum(o.astype(F32))
 
     text = _compile(
         chip, jax.grad(loss, argnums=(0, 1, 2)),
-        ((B, S, H, D), BF16), ((B, S, H, D), BF16), ((B, S, H, D), BF16),
+        ((B, S, H * D), BF16), ((B, S, Hkv * D), BF16), ((B, S, Hkv * D), BF16),
     )
-    block, rows = f"bf16[{B * H},{S},{D}]", f"f32[{B * H},{S},128]"
-    results = [line.split(" = ", 1)[1].split(" custom-call(")[0]
-               for line in text.splitlines()
-               if 'custom_call_target="tpu_custom_call"' in line]
-    assert len(results) == 3, results
-    fwd = [r for r in results if r.count(block) == 1 and rows in r]
-    dq = [r for r in results if r.count(block) == 1 and r.startswith("bf16[")]
-    dkv = [r for r in results if r.count(block) == 2]
-    assert len(fwd) == len(dq) == len(dkv) == 1, results
+    kernels = {}
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line and "flash" in line:
+            name = re.search(r"%(?:\w+?_)?(flash_attention_\w+?)_*\.\d+ = ", line)
+            kernels.setdefault(name.group(1), []).append(line)
+    assert sorted(kernels) == ["flash_attention_dkv", "flash_attention_dq",
+                               "flash_attention_fwd"], sorted(kernels)
+    rows = {f"bf16[{B},{S},{H * D}]", f"bf16[{B},{S},{Hkv * D}]"}
+    for name, lines in kernels.items():
+        assert len(lines) == 1, (name, lines)
+        blocks = re.findall(r"bf16\[[\d,]+\]", lines[0].split(", custom_call_target")[0])
+        assert blocks and set(blocks) <= rows, (name, blocks)
+    shapes = "|".join(re.escape(x) for x in (
+        f"[{B},{H},{S},{D}]", f"[{B * H},{S},{D}]", f"[{B},{S},{H},{D}]",
+        f"[{B},{S},{H * D}]", f"[{B},{Hkv},{S},{D}]", f"[{B * Hkv},{S},{D}]",
+        f"[{B},{S},{Hkv},{D}]", f"[{B},{S},{Hkv * D}]"))
+    for line in text.splitlines():
+        op = re.match(r"\s*(?:ROOT )?%\S+ = (\S+) (\w[\w-]*)\(", line)
+        if not op:
+            continue
+        shape, kind = op.groups()
+        assert not (kind in ("copy", "transpose") and re.search(shapes, shape)), line
+        assert not (kind == "broadcast" and re.fullmatch(r"f32\[[\d,]*,128\]\S*", shape)), line
+
+
+# (B, S, H, D, per-row positions): the ESM-2 cell's q, and qwen2-7b's q
+# in a prefill of 37 tokens and of a prompt whose rows span several
+# blocks, and with a position per row
+ROPE = {
+    "esm2-650m.mlm": (16, 1024, 20, 64, False),
+    "qwen2-7b.prefill37": (1, 37, 28, 128, False),
+    "qwen2-7b.prefill4096": (1, 4096, 28, 128, False),
+    "qwen2-7b.rows": (8, 256, 28, 128, True),
+}
+
+
+@pytest.mark.parametrize("arch", sorted(ROPE))
+def test_rope_fwd_bwd(chip, arch):
+    B, S, H, D, rows = ROPE[arch]
+
+    def loss(x, pos):
+        return jnp.sum(ops.rope(x, pos, 1e6, impl="pallas").astype(F32))
+
+    _compile(chip, jax.grad(loss), ((B, S, H, D), BF16),
+             ((B, S) if rows else (S,), I32))
 
 
 # (tokens, d_model, padded vocab, vocab)
@@ -156,6 +225,7 @@ def test_training_kernels_carry_their_names(chip):
     import re
 
     def loss(q, k, v, h, w, t):
+        q = ops.rope(q, jnp.arange(256), 1e4, impl="pallas")
         o = ops.attention(q, k, v, causal=False, impl="pallas")
         ce, _ = ops.cross_entropy(h, w, t, vocab=33, impl="pallas")
         return jnp.sum(o.astype(F32)) + jnp.sum(ce)
@@ -168,7 +238,7 @@ def test_training_kernels_carry_their_names(chip):
     )
     calls = [line.split(" = ")[0].strip() for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
-    kernels = ["flash_attention_fwd", "flash_attention_dq",
+    kernels = ["rope", "rope", "flash_attention_fwd", "flash_attention_dq",
                "flash_attention_dkv", "cross_entropy_fwd", "cross_entropy_dh",
                "cross_entropy_dw"]
     assert len(calls) == len(kernels), calls
