@@ -1,7 +1,6 @@
 """Batched serving example: train a small SMILES seq2seq (MolMIM-class)
 briefly, then serve a batch of requests — prefill + greedy decode with the
-framework's KV-cache path (the same decode_step the 32k/500k dry-run shapes
-lower).
+framework's KV-cache path (the same decode_step the serving engine runs).
 
     PYTHONPATH=src python examples/serve_generate.py
 """

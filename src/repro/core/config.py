@@ -193,11 +193,6 @@ class ParallelConfig:
     attention_parallelism: str = "head_tp"   # head_tp | context
     fsdp_axes: Tuple[str, ...] = ("data",)   # axes weights are FSDP-sharded over
     expert_axis: str = "model"
-    remat_policy: str = "block"              # none | block | dots | full
-    shard_cache_seq: bool = True             # decode: shard KV cache over seq
-    scan_layers: bool = True
-    optimizer_state_dtype: str = "float32"   # float32 | bfloat16
-    donate_params: bool = True
 
     def validate(self, mc: ModelConfig, tp: int) -> "ParallelConfig":
         """Auto-downgrade head_tp -> context when heads don't divide tp."""

@@ -18,10 +18,6 @@ _DTYPES = {
 }
 
 
-def dtype_of(name: str):
-    return _DTYPES[name]
-
-
 @dataclass(frozen=True)
 class Policy:
     param_dtype: str = "float32"

@@ -7,9 +7,7 @@ Three implementations exist for each hot-spot:
                   with their Pallas backward kernels, so ``impl="pallas"``
                   (and ``auto`` on TPU) is trainable.
   * ``xla``     — blockwise/scanned jnp with the same O(block) memory
-                  behavior, autodiff-able; used on CPU, in the dry-run
-                  lowering (keeps HLO memory honest) and as the CPU/fallback
-                  training path.
+                  behavior, autodiff-able; the CPU and fallback path.
   * ``naive``   — the oracle in ``ref.py`` (tests only).
 
 ``impl="auto"`` resolves to pallas on TPU, xla elsewhere.
@@ -36,17 +34,14 @@ from repro.kernels.ssd_scan import ssd_scan as _ssd_pallas
 
 NEG_INF = -1e30
 
-
-import os
-
+# kv block of the xla blockwise attention scan: the fp32 (m, l, acc) carries
+# round-trip HBM once per block, so carry traffic scales 1/block
+XLA_ATTN_BLOCK_K = 2048
 
 _IMPLS = ("auto", "pallas", "pallas_interpret", "xla", "naive")
 
 
 def _resolve(impl: str, interpret: bool) -> Tuple[str, bool]:
-    forced = os.environ.get("REPRO_FORCE_IMPL", "")
-    if forced:
-        impl = forced  # benchmark harness: force naive/xla/pallas globally
     if impl not in _IMPLS:
         raise ValueError(f"unknown kernel impl {impl!r}; expected one of {_IMPLS}")
     if impl == "auto":
@@ -65,16 +60,11 @@ def is_pallas(impl: str) -> bool:
 # --------------------------------------------------------------------- #
 # attention
 # --------------------------------------------------------------------- #
-def _blockwise_attention_xla(
-    q, k, v, *, causal, window, softcap, q_offset, block_k=0
-):
+def _blockwise_attention_xla(q, k, v, *, causal, window, softcap, q_offset):
     """Flash-attention semantics as a lax.scan over kv blocks (O(S·block) mem).
 
-    Tuning knobs found via dry-run traffic analysis (EXPERIMENTS.md §Perf
-    scout iter-3):
-      * block_k defaults to 2048 (env REPRO_ATTN_BLOCK_K) — the fp32
-        (m, l, acc) scan carries round-trip HBM once per kv block, so
-        carry traffic scales 1/block_k;
+    What bounds its HBM traffic:
+      * kv blocks of ``XLA_ATTN_BLOCK_K`` rows;
       * probability blocks are cast to the input dtype (bf16) before the
         PV matmul with fp32 accumulation — halves the largest per-block
         buffer, mirroring what the MXU kernel does;
@@ -83,10 +73,8 @@ def _blockwise_attention_xla(
     B, S, H, D = q.shape
     T, Hkv = k.shape[1], k.shape[2]
     group = H // Hkv
-    if block_k <= 0:
-        block_k = int(os.environ.get("REPRO_ATTN_BLOCK_K", "2048"))
     # zero-pad the kv tail block; masked below via k_pos < T
-    block_k, Tp = tiling.pick_block(T, block_k)
+    block_k, Tp = tiling.pick_block(T, XLA_ATTN_BLOCK_K)
     k = tiling.pad_dim(k, 1, Tp)
     v = tiling.pad_dim(v, 1, Tp)
     nblk = Tp // block_k
@@ -553,9 +541,8 @@ def sample_tokens(
 # The xla paths use custom VJPs engineered so every FULL-SIZE fusion output
 # stays in the input dtype (bf16); only per-row statistics are fp32.  The
 # autodiff'd fp32-math norm materializes fp32 residual-stream buffers in
-# fwd+bwd+remat — found via the dry-run traffic breakdown (llama3-405b:
-# 48% of HBM traffic; EXPERIMENTS.md §Perf llama3 iter-2).  This mirrors
-# what the fused Pallas/Apex norm kernels do on real hardware.
+# fwd+bwd+remat (in llama3-405b's compiled step, 48% of its HBM traffic).
+# This mirrors what the fused Pallas/Apex norm kernels do on real hardware.
 # --------------------------------------------------------------------- #
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
 def _rmsnorm_xla(x, w, eps):
@@ -692,8 +679,7 @@ def _blockwise_ce_xla(hidden, w_out, targets, *, vocab, block_v=2048):
     The matmuls run in the input dtype with fp32 ACCUMULATION
     (preferred_element_type) instead of upcasting `hidden` to fp32 — an
     upfront fp32 cast makes the hidden cotangent fp32 and cascades fp32
-    residual-stream buffers through the entire backward pass (found via
-    the dry-run traffic breakdown; EXPERIMENTS.md §Perf llama3 iter-1)."""
+    residual-stream buffers through the entire backward pass."""
     T, D = hidden.shape
     Vp = w_out.shape[1]
     # zero-pad the vocab tail; masked below via col < vocab
@@ -806,8 +792,8 @@ def _ssd_chunked_xla(x, dt, A, Bm, Cm, D, *, chunk=64, init_state=None):
         decay = jnp.where(causal[None, :, :, None], jnp.exp(diff), 0.0)
         scores = jnp.einsum("blhn,bshn->blsh", cc, bc)
         # the (L × L) attention-like weights feed an MXU matmul: store them
-        # in the input dtype with fp32 accumulation (EXPERIMENTS §Perf
-        # jamba iter-4) — decay statistics stay fp32.
+        # in the input dtype with fp32 accumulation — decay statistics
+        # stay fp32.
         att = (scores * decay * dtc[:, None, :, :]).astype(x.dtype)
         y = jnp.einsum(
             "blsh,bshp->blhp", att, xc.astype(x.dtype),

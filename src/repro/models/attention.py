@@ -2,8 +2,8 @@
 KV caching (prefill + decode), sliding window, and optional cross-attention.
 
 Cache layout: {"k": (B, T, Hkv, D), "v": (B, T, Hkv, D)} with the sequence
-dim logically ``cache_seq`` (sharded over `model` when enabled — decode then
-lowers to flash-decoding-style partial-stat all-reduces, see ops.decode_attention).
+dim logically ``cache_seq`` (sharded over `model` — decode then lowers to
+flash-decoding-style partial-stat all-reduces, see ops.decode_attention).
 """
 from __future__ import annotations
 

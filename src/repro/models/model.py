@@ -1,7 +1,7 @@
 """Top-level model: param tree assembly + train/prefill/decode entry points.
 
-``Model`` is the single public handle the launcher, trainer, server, tests
-and dry-run all use.  It is architecture-generic: the config decides dense /
+``Model`` is the single public handle the launcher, trainer, server and
+tests all use.  It is architecture-generic: the config decides dense /
 MoE / SSM / hybrid / enc-dec / frontend-stub wiring.
 """
 from __future__ import annotations
@@ -349,9 +349,7 @@ class Model:
         maintains; ``pos`` is per-slot ``(batch,)`` in that layout.
 
         The cache is built with implicit (single-device) placement even
-        when the model carries a mesh ``ShardingCtx`` — this function is
-        also called under ``jax.eval_shape`` (launch/shapes.dryrun_bundle)
-        where no buffers may be materialized.  Mesh consumers place it
+        when the model carries a mesh ``ShardingCtx``.  Mesh consumers place it
         explicitly via ``cache_shardings``: the serving engine device_puts
         the tree once at construction and pins every per-step jit to the
         same specs.

@@ -170,18 +170,6 @@ def _apply_sublayer(
     return x, new_cache, aux
 
 
-def _remat_wrap(fn, policy: str):
-    if policy == "none":
-        return fn
-    if policy == "dots":
-        return jax.checkpoint(
-            fn, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-        )
-    if policy == "full":
-        return jax.checkpoint(fn, policy=jax.checkpoint_policies.everything_saveable)
-    return jax.checkpoint(fn)  # "block": save only unit boundaries
-
-
 # --------------------------------------------------------------------- #
 # stacks
 # --------------------------------------------------------------------- #
@@ -227,26 +215,10 @@ def decoder_stack(
 
     body = unit_body
     if mode == "train":
-        body = _remat_wrap(unit_body, ctx.pc.remat_policy)
+        # recompute each unit in the backward: only unit boundaries are saved
+        body = jax.checkpoint(unit_body)
 
     aux0 = jnp.zeros(aux_shape(cfg), jnp.float32)
-
-    if not ctx.pc.scan_layers:
-        n = num_units(cfg)
-        carry = (x, aux0)
-        new_caches = []
-        for j in range(n):
-            up = jax.tree.map(lambda p: p[j], stacked_params)
-            uc = jax.tree.map(lambda c: c[j], caches) if caches is not None else None
-            carry, nc = body(carry, (up, uc))
-            new_caches.append(nc)
-        (x, aux_sum) = carry
-        stacked_cache = (
-            jax.tree.map(lambda *cs: jnp.stack(cs), *new_caches)
-            if (mode != "train" and new_caches and new_caches[0])
-            else None
-        )
-        return x, stacked_cache, aux_sum
 
     if caches is None:
         (x, aux_sum), new_caches = jax.lax.scan(

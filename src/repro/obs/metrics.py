@@ -20,9 +20,9 @@ counters.  Design constraints, in order:
   * **Two export formats.**  ``to_prometheus()`` writes the standard
     text exposition (``# HELP`` / ``# TYPE`` / samples, cumulative
     histogram buckets with ``+Inf``); ``dump_json()`` appends one
-    timestamped record to a ``{"runs": [...]}`` trajectory file — the
-    same shape as the repo's ``BENCH_*.json`` perf trajectories — with
-    the tmp-file + ``os.replace`` atomicity of ``checkpoint/ckpt.py``.
+    timestamped record to a ``{"runs": [...]}`` trajectory file (the
+    launchers' ``--metrics-dir`` snapshots) with the tmp-file +
+    ``os.replace`` atomicity of ``checkpoint/ckpt.py``.
 
 Histograms are fixed-bucket (Prometheus-style): quantiles come from
 linear interpolation inside the bucket that crosses the target rank, so
@@ -336,8 +336,7 @@ class MetricsRegistry:
                   extra: Optional[dict] = None) -> None:
         """Append one snapshot record to a ``{"runs": [...]}`` trajectory.
 
-        Same file shape and atomic-write discipline as the repo's
-        ``BENCH_*.json`` perf trajectories (``benchmarks/run.py``): each
+        This is the ``--metrics-dir`` JSON snapshot of the launchers: each
         record is ``{"timestamp", "rows", ...extra}``, the whole file is
         rewritten to a tmp path and ``os.replace``d, so a reader never
         sees a torn snapshot.  ``now`` is injectable (epoch seconds) for

@@ -1,9 +1,9 @@
 """AdamW with sharded states (no optax dependency).
 
 States inherit the parameter PartitionSpecs (ZeRO-3-like: fully sharded
-optimizer).  ``state_dtype`` selects fp32 (faithful Megatron) or bf16
-moments (beyond-paper memory optimization used for llama3-405b — see
-EXPERIMENTS.md §Perf).
+optimizer).  ``state_dtype`` selects fp32 (faithful Megatron; the
+trainer's choice) or bf16 moments (beyond-paper memory optimization that
+halves the optimizer state).
 """
 from __future__ import annotations
 

@@ -13,10 +13,8 @@ Consumers of these rules span both halves of the system:
     axes via ``host_batch_sharding``; parity with the single-device run
     is asserted in tests/test_trainer_distributed.py (8-virtual-device
     CPU mesh) and tests/test_parallel_numerics.py.
-  * serving / dry-run — ``launch/shapes.dryrun_bundle`` shards the
-    prefill/decode entry points for the 256/512-chip compile-only sweep,
-    and ``serving/engine.Engine`` runs tensor-parallel inference end to
-    end: ``Model.cache_specs`` (built from these rules) pins the
+  * serving — ``serving/engine.Engine`` runs tensor-parallel inference
+    end to end: ``Model.cache_specs`` (built from these rules) pins the
     in/out shardings of every per-step jit so the paged K/V pools shard
     over the head (``model``) axis while the host-side page allocator
     stays global — parity with the single-device engine is asserted in
@@ -58,7 +56,7 @@ def axis_rules(pc: ParallelConfig, mesh: Mesh) -> Dict[str, Any]:
         "tp": "model",
         "experts": pc.expert_axis,
         "layers": None,
-        "cache_seq": "model" if pc.shard_cache_seq else None,
+        "cache_seq": "model",
         "cache_batch": batch_axes,
         "vocab": "model",
         "kv_tp": "model",

@@ -121,8 +121,8 @@ structured event stamped by the engine's injectable clock, so a seeded
 fault run yields a byte-identical JSONL trace.  Both hooks are
 host-side appends on paths the engine already walks: the
 one-bulk-transfer-per-step contract is unchanged (transfer-guard
-asserted in ``tests/test_obs.py``) and the measured tok/s overhead is
-bounded <2% in ``benchmarks/serving_bench.py``.  Every ``step()`` is a
+asserted in ``tests/test_obs.py``) and the tokens are the same as with
+both off (``tests/test_serving_engine.py``).  Every ``step()`` is a
 record of the step log (``repro.obs.STEP_LOG``, kind ``"engine"``) with
 host spans ``engine.prefill`` (each prompt's prefill dispatch),
 ``engine.decode`` (the fused decode dispatch) and ``engine.host_sync``

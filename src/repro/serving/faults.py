@@ -29,9 +29,7 @@ Schedules are keyed on the engine's own step counter (``Engine.steps``,
 1-based: the first ``step()`` call is step 1), so a plan replays
 identically run-to-run — the chaos suite in
 ``tests/test_engine_faults.py`` asserts engine invariants under
-:meth:`FaultPlan.seeded` plans across many seeds, and
-``benchmarks/serving_bench.py`` drives a degraded-mode workload with
-the same machinery.
+:meth:`FaultPlan.seeded` plans across many seeds.
 
 Usage::
 

@@ -1,4 +1,4 @@
-"""Train/serve step builders: loss + grad + clip + AdamW, with shardings.
+"""Train step builders: loss + grad + clip + AdamW, with shardings.
 
 ``make_train_step`` builds the raw ``step_fn(state, batch)`` — including
 microbatch gradient accumulation (``TrainConfig.accum_steps``) and the
@@ -7,10 +7,10 @@ fp32 master copy held in ``TrainState``; see ``core/precision.compute_view``).
 
 ``make_sharded_train_step`` is the distributed entry point: it consumes
 ``train_state_specs(model)`` / the model's ``ShardingCtx`` and returns
-``jit(step_fn, in_shardings=…, out_shardings=…, donate_argnums=…)`` — the
+``jit(step_fn, in_shardings=…, out_shardings=…, donate_argnums=0)`` — the
 same builder serves CPU unit tests (mesh=None), the 8-virtual-device CPU
-mesh (``--xla_force_host_platform_device_count=8``) and the 256/512-chip
-production mesh.  ``training/loop.Trainer`` drives it end-to-end.
+mesh (``--xla_force_host_platform_device_count=8``) and the chip.
+``training/loop.Trainer`` drives it end-to-end.
 """
 from __future__ import annotations
 
@@ -20,9 +20,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec
 
-from repro.core.config import ModelConfig, ParallelConfig, TrainConfig
-from repro.core.precision import compute_view, dtype_of
-from repro.models.model import Model, build_model
+from repro.core.config import TrainConfig
+from repro.core.precision import compute_view
+from repro.models.model import Model
 from repro.optim import adamw
 from repro.optim.schedule import lr_at
 
@@ -49,14 +49,12 @@ jax.tree_util.register_pytree_node(
 
 def init_train_state(model: Model, key, tc: TrainConfig) -> TrainState:
     params = model.init(key)
-    sdt = dtype_of(model.ctx.pc.optimizer_state_dtype)
-    return TrainState(params, adamw.init_state(params, sdt))
+    return TrainState(params, adamw.init_state(params))
 
 
 def abstract_train_state(model: Model) -> TrainState:
     params = model.abstract_params()
-    sdt = dtype_of(model.ctx.pc.optimizer_state_dtype)
-    z = lambda p: jax.ShapeDtypeStruct(p.shape, sdt)
+    z = lambda p: jax.ShapeDtypeStruct(p.shape, jnp.float32)
     opt = adamw.AdamWState(
         step=jax.ShapeDtypeStruct((), jnp.int32),
         mu=jax.tree.map(z, params),
@@ -210,10 +208,9 @@ def make_sharded_train_step(model: Model, tc: TrainConfig):
     degrades to a plain donated jit, so the same builder runs everywhere.
     """
     step_fn = make_train_step(model, tc)
-    donate = (0,) if model.ctx.pc.donate_params else ()
     mesh = model.ctx.mesh
     if mesh is None or mesh.empty or mesh.size == 1:
-        return jax.jit(step_fn, donate_argnums=donate)
+        return jax.jit(step_fn, donate_argnums=0)
     state_sh = state_shardings(model)
     batch_sh = host_batch_sharding(model)
     metrics_sh = NamedSharding(mesh, PartitionSpec())
@@ -221,7 +218,7 @@ def make_sharded_train_step(model: Model, tc: TrainConfig):
         step_fn,
         in_shardings=(state_sh, batch_sh),
         out_shardings=(state_sh, metrics_sh),
-        donate_argnums=donate,
+        donate_argnums=0,
     )
 
 
@@ -241,26 +238,3 @@ def init_sharded_train_state(model: Model, key, tc: TrainConfig) -> TrainState:
     if mesh is None or mesh.empty or mesh.size == 1:
         return state
     return jax.device_put(state, state_shardings(model))
-
-
-def make_eval_step(model: Model):
-    def eval_fn(params, batch):
-        loss, metrics = model.loss_fn(params, batch)
-        return metrics
-
-    return eval_fn
-
-
-# ------------------------------------------------------------------ serving
-def make_prefill_step(model: Model, max_len: int):
-    def prefill_fn(params, batch):
-        return model.prefill(params, batch, max_len)
-
-    return prefill_fn
-
-
-def make_decode_step(model: Model):
-    def decode_fn(params, cache, tokens):
-        return model.decode_step(params, cache, tokens)
-
-    return decode_fn

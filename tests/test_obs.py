@@ -182,7 +182,7 @@ def test_dump_json_matches_trajectory_shape(tmp_path):
     reg.dump_json(path, now=60.0)
     with open(path) as f:
         doc = json.load(f)
-    assert set(doc) == {"runs"}         # BENCH_*.json trajectory shape
+    assert set(doc) == {"runs"}         # {"runs": [...]} trajectory shape
     assert len(doc["runs"]) == 2        # appended, not clobbered
     first, second = doc["runs"]
     assert first["timestamp"] == "1970-01-01T00:00:00Z"
@@ -496,7 +496,7 @@ def test_scope_map_is_built_lazily_on_a_tiny_model(tmp_path, monkeypatch):
     """The trainer registers each compiled step program; its scope map is
     read from the compiled HLO only at the first read, names every layer
     scope, and tells the passes apart (the tiny model's blocks are
-    rematerialised under the default ``remat_policy="block"``)."""
+    rematerialised: each unit body is a ``jax.checkpoint``)."""
     import repro.training.loop as loop
 
     texts = []

@@ -7,7 +7,8 @@ import pytest
 
 from repro.core.config import ModelConfig
 from repro.models.model import build_model
-from repro.serving.engine import Engine, Request
+from repro.obs import MetricsRegistry, TraceRecorder
+from repro.serving.engine import Engine, EngineOverloaded, Request
 
 
 def build(family="dense", **over):
@@ -37,21 +38,39 @@ def isolated_greedy(model, params, prompt, n, max_len=64):
     return out
 
 
-@pytest.mark.parametrize("layout", ["dense", "paged"])
+@pytest.mark.parametrize(
+    "layout", ["dense", "paged", "paged-telemetry", "paged-bounded"])
 def test_engine_matches_isolated_decoding(layout):
+    """Each request's tokens equal its isolated decode, in two passes over
+    one engine; with telemetry on; and, behind a bounded queue that sheds
+    the overflow, for every request it accepts."""
     model, params = build()
     rng = np.random.default_rng(0)
     prompts = [rng.integers(0, 64, size=L).astype(np.int32) for L in (5, 9, 7, 12, 6)]
     n_new = 6
-    eng = Engine(model, params, slots=2, max_len=64, cache_layout=layout,
-                 page_size=8)
-    for i, p in enumerate(prompts):
-        eng.submit(Request(uid=i, prompt=p, max_new=n_new))
-    done = eng.run()
-    assert len(done) == len(prompts)
-    for req in done:
-        want = isolated_greedy(model, params, prompts[req.uid], n_new)
-        assert req.output == want, (req.uid, req.output, want)
+    kw = {
+        "paged-telemetry": dict(metrics=MetricsRegistry(),
+                                trace=TraceRecorder()),
+        "paged-bounded": dict(max_queue=3),
+    }.get(layout, {})
+    eng = Engine(model, params, slots=2, max_len=64,
+                 cache_layout=layout.split("-")[0], page_size=8, **kw)
+    want = {i: isolated_greedy(model, params, p, n_new)
+            for i, p in enumerate(prompts)}
+    for _ in range(2):
+        accepted = set()
+        for i, p in enumerate(prompts):
+            try:
+                eng.submit(Request(uid=i, prompt=p, max_new=n_new))
+                accepted.add(i)
+            except EngineOverloaded:
+                assert layout == "paged-bounded"
+        assert len(accepted) == (3 if layout == "paged-bounded" else 5)
+        n_done = len(eng.done)
+        done = eng.run()[n_done:]
+        assert {r.uid for r in done} == accepted
+        for req in done:
+            assert req.output == want[req.uid], (req.uid, req.output)
 
 
 def test_engine_ssm_family():
